@@ -12,14 +12,10 @@ from __future__ import annotations
 import numpy as np
 
 import jax
+# `with mesh:` records its mesh only in this thread-local stack: the
+# public jax.sharding.get_mesh / get_abstract_mesh see jax.set_mesh alone
+from jax._src.mesh import thread_resources as _thread_resources
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-# `with mesh:` state only has internal accessors pre-jax-0.5; resolve one
-# at import so a dependency bump degrades loudly here, not deep in a jit
-try:
-    from jax.interpreters.pxla import thread_resources as _thread_resources
-except ImportError:                              # moved in newer jax
-    from jax._src.mesh import thread_resources as _thread_resources
 
 # logical axes: data parallelism spans pod x data; sequence parallelism
 # reuses the model axis (tensor and sequence sharding never coexist on
@@ -61,6 +57,21 @@ def fspec(mesh, *axes) -> P:
         else:
             out.append(ax if ax in names else None)
     return P(*out)
+
+
+def batch_local(fn, x):
+    """`fn(x)`, with each device of the active mesh applying `fn` to its
+    own BATCH rows of `x` (shard_map), for ops the SPMD partitioner
+    cannot split, such as Mosaic kernels.  `fn` must act on rows
+    independently.  A batch the BATCH axes do not divide is replicated;
+    with no mesh active this is plain `fn(x)`."""
+    mesh = current_mesh()
+    if mesh is None:
+        return fn(x)
+    spec = fspec(mesh, BATCH) if x.shape[0] % dp_size(mesh) == 0 else P()
+    # check_vma=False: a pallas_call states no per-axis variance
+    return jax.shard_map(fn, mesh=mesh, in_specs=spec, out_specs=spec,
+                         check_vma=False)(x)
 
 
 def shard(x, *axes):

@@ -1,7 +1,9 @@
 """jit'd public wrappers around the Pallas kernels: padding to tile
 boundaries (zeros are exact in integer arithmetic), batching, and the
-interpret-mode switch (interpret=True executes the kernel body in Python —
-the validation mode on this CPU container; on TPU pass interpret=False).
+interpret-mode switch.  `interpret=None` resolves it from the platform
+(`default_interpret`): compiled Mosaic kernels on a TPU, interpret mode
+(the kernel body run as plain JAX ops) anywhere else.  The kernel entry
+points themselves take `interpret` with no default.
 """
 from __future__ import annotations
 

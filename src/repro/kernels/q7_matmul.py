@@ -50,7 +50,7 @@ def _q7_matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, n_k: int,
                                              "bk", "interpret"))
 def q7_matmul_pallas(a, b, *, shift: int, rounding: str = "floor",
                      bm: int = 128, bn: int = 128, bk: int = 128,
-                     interpret: bool = True):
+                     interpret: bool):
     """a [M,K] int8, b [K,N] int8 -> int8 [M,N].  Caller pads to tiles
     (zeros are exact in integer arithmetic)."""
     M, K = a.shape

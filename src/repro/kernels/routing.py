@@ -33,8 +33,9 @@ def _isqrt(n):
     return jnp.where(n <= 1, n, jax.lax.fori_loop(0, 32, body, x0))
 
 
-def _squash_rows(s32, in_frac: int, out_frac: int = 7):
-    Q = jnp.sum(s32 * s32, axis=-1, keepdims=True)
+def _squash_caps(s32, in_frac: int, out_frac: int = 7):
+    """Integer squash of each capsule s [J, O, 1] over its O axis."""
+    Q = jnp.sum(s32 * s32, axis=1, keepdims=True)
     S = _isqrt(Q)
     P = SQUASH_GUARD_BITS
     shift = out_frac - in_frac + P
@@ -46,7 +47,7 @@ def _squash_rows(s32, in_frac: int, out_frac: int = 7):
 
 
 def _softmax_q7_cols(b32, in_frac: int):
-    """Shift-based integer softmax over axis 0 (the J axis of b [J, I])."""
+    """Shift-based integer softmax over axis 0 (the J axis of b)."""
     m = jnp.max(b32, axis=0, keepdims=True)
     e = jnp.maximum(jnp.right_shift(b32 - m, in_frac), -20)
     p = jnp.left_shift(jnp.ones_like(e), 20 + e)
@@ -66,22 +67,26 @@ def _rshift_sat8(acc, shift: int, rounding: str):
 
 def _routing_kernel(u_ref, v_ref, *, num_iters, caps_out_shifts,
                     caps_out_fracs, agree_shifts, logit_frac, rounding):
-    u = u_ref[0].astype(jnp.int32)              # [J, I, O] resident in VMEM
-    J, I, O = u.shape
-    b = jnp.zeros((J, I), jnp.int32)
-    v = jnp.zeros((J, O), jnp.int32)
+    # I sits on the 128-wide lane axis and O on the sublanes; every
+    # intermediate keeps rank 3 (keepdims) so no reduction or broadcast
+    # has to move data between the two axes.  The two contractions are a
+    # VPU multiply plus a reduction: at O = 4-6 they are far too narrow
+    # for the MXU, and Mosaic cannot lower a batched dot in which one
+    # operand has no free dimension.
+    u = u_ref[0].astype(jnp.int32)              # [J, O, I] resident in VMEM
+    J, O, I = u.shape
+    b = jnp.zeros((J, 1, I), jnp.int32)
+    v = None
     for r in range(num_iters):
-        c = _softmax_q7_cols(b, logit_frac)                      # [J, I]
-        s = jnp.einsum("ji,jio->jo", c, u,
-                       preferred_element_type=jnp.int32)
+        c = _softmax_q7_cols(b, logit_frac)                      # [J, 1, I]
+        s = jnp.sum(c * u, axis=2, keepdims=True)                # [J, O, 1]
         s_q = _rshift_sat8(s, caps_out_shifts[r], rounding)
-        v = _squash_rows(s_q, in_frac=caps_out_fracs[r])         # [J, O]
+        v = _squash_caps(s_q, in_frac=caps_out_fracs[r])         # [J, O, 1]
         if r < num_iters - 1:
-            a = jnp.einsum("jio,jo->ji", u, v,
-                           preferred_element_type=jnp.int32)
+            a = jnp.sum(u * v, axis=1, keepdims=True)            # [J, 1, I]
             a = _rshift_sat8(a, agree_shifts[r], rounding)
             b = jnp.clip(b + a, INT8_MIN, INT8_MAX)              # q7 add
-    v_ref[0] = v.astype(jnp.int8)
+    v_ref[0] = v
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -89,19 +94,22 @@ def _routing_kernel(u_ref, v_ref, *, num_iters, caps_out_shifts,
     "logit_frac", "rounding", "interpret"))
 def routing_q7_pallas(u_hat, *, num_iters: int, caps_out_shifts: tuple,
                       caps_out_fracs: tuple, agree_shifts: tuple,
-                      logit_frac: int, rounding: str = "floor",
-                      interpret: bool = True):
-    """u_hat int8 [B, J, I, O] -> v int8 [B, J, O], all r iterations fused."""
+                      logit_frac: int, rounding: str, interpret: bool):
+    """u_hat int8 [B, J, I, O] -> v int8 [B, J, O], all r iterations fused.
+
+    The kernel reads u_hat as [B, J, O, I], with I on the lanes; the
+    transpose is an XLA op outside the kernel."""
     B, J, I, O = u_hat.shape
-    return pl.pallas_call(
+    v = pl.pallas_call(
         functools.partial(
             _routing_kernel, num_iters=num_iters,
             caps_out_shifts=caps_out_shifts, caps_out_fracs=caps_out_fracs,
             agree_shifts=agree_shifts, logit_frac=logit_frac,
             rounding=rounding),
         grid=(B,),
-        in_specs=[pl.BlockSpec((1, J, I, O), lambda b: (b, 0, 0, 0))],
-        out_specs=pl.BlockSpec((1, J, O), lambda b: (b, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, J, O), jnp.int8),
+        in_specs=[pl.BlockSpec((1, J, O, I), lambda b: (b, 0, 0, 0))],
+        out_specs=pl.BlockSpec((1, J, O, 1), lambda b: (b, 0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, J, O, 1), jnp.int32),
         interpret=interpret,
-    )(u_hat)
+    )(jnp.swapaxes(u_hat, 2, 3))
+    return v[..., 0].astype(jnp.int8)

@@ -48,7 +48,7 @@ def _squash_kernel(s_ref, o_ref, *, in_frac: int, out_frac: int):
 @functools.partial(jax.jit, static_argnames=("in_frac", "out_frac",
                                              "block_rows", "interpret"))
 def squash_q7_pallas(s, *, in_frac: int, out_frac: int = 7,
-                     block_rows: int = 256, interpret: bool = True):
+                     block_rows: int = 256, interpret: bool):
     """s int8 [R, D] -> int8 [R, D] (rows padded by the ops wrapper)."""
     R, D = s.shape
     br = min(block_rows, R)
@@ -72,7 +72,7 @@ def _squash_float_kernel(s_ref, o_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
-def squash_float_pallas(s, *, block_rows: int = 256, interpret: bool = True):
+def squash_float_pallas(s, *, block_rows: int = 256, interpret: bool):
     """Float squash (Eq. 1) for the fp training path."""
     R, D = s.shape
     br = min(block_rows, R)

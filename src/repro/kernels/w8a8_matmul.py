@@ -46,7 +46,7 @@ def _w8a8_kernel(a_ref, w_ref, sh_ref, o_ref, acc_ref, *, n_k: int,
                                              "interpret"))
 def w8a8_matmul_pallas(a, w, col_shift, *, rounding: str = "nearest",
                        bm: int = 128, bn: int = 128, bk: int = 128,
-                       interpret: bool = True):
+                       interpret: bool):
     """a [M,K] int8, w [K,N] int8, col_shift [N] int32 -> int8 [M,N]."""
     M, K = a.shape
     _, N = w.shape

@@ -57,8 +57,6 @@ def analyze_cell(compiled, cfg: ModelConfig, shape: ShapeSpec, mesh,
     chips = mesh_chips(mesh)
     peak = PEAK_INT8 if int8 else PEAK_BF16
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):        # older jax: one dict per device
-        ca = ca[0] if ca else {}
 
     # XLA's cost_analysis counts while bodies once (everything here is
     # scanned) -> use our own trip-count-aware HLO cost model instead,

@@ -23,6 +23,7 @@ import warnings
 
 import jax.numpy as jnp
 
+from repro.dist import api
 from repro.nn.variants import REGISTRY
 from repro.obs import METRICS, MetricsRegistry
 from repro.quant import int8_ops as q
@@ -144,7 +145,9 @@ class PallasBackend(JnpBackend):
             return super().squash_q7(s, in_frac=in_frac, out_frac=out_frac,
                                      impl=impl)
         from repro.kernels import ops as kops
-        return kops.squash_q7(s, in_frac=in_frac, out_frac=out_frac)
+        return api.batch_local(
+            lambda x: kops.squash_q7(x, in_frac=in_frac, out_frac=out_frac),
+            s)
 
     def routing_q7(self, u_hat, plan, *, rounding):
         # the fused kernel implements only the default variants and the
@@ -156,14 +159,17 @@ class PallasBackend(JnpBackend):
             self._fallback("routing.squash", plan.squash_impl)
             return _JNP_ORACLE.routing_q7(u_hat, plan, rounding=rounding)
         if plan.out_frac != 7:
-            return super().routing_q7(u_hat, plan, rounding=rounding)
+            self._fallback("routing.out_frac", f"Q0.{plan.out_frac}")
+            return _JNP_ORACLE.routing_q7(u_hat, plan, rounding=rounding)
         from repro.kernels import ops as kops
-        return kops.routing_q7(
-            u_hat, num_iters=plan.routings,
-            caps_out_shifts=plan.caps_out_shifts,
-            caps_out_fracs=plan.caps_out_fracs,
-            agree_shifts=plan.agree_shifts,
-            logit_frac=plan.logit_frac, rounding=rounding)
+        return api.batch_local(
+            lambda u: kops.routing_q7(
+                u, num_iters=plan.routings,
+                caps_out_shifts=plan.caps_out_shifts,
+                caps_out_fracs=plan.caps_out_fracs,
+                agree_shifts=plan.agree_shifts,
+                logit_frac=plan.logit_frac, rounding=rounding),
+            u_hat)
 
 
 BACKENDS = {"jnp": JnpBackend(), "pallas": PallasBackend(metrics=METRICS)}

@@ -7,7 +7,9 @@ quantize the float images, run the int8 pipeline (`CapsPipeline
 with `dist.api.shard` constraints on the logical BATCH axis at the wave
 boundary.  Under a mesh, GSPMD splits the wave's rows across the BATCH
 (pod x data) axes; with no mesh (or a 1-device mesh) `api.shard` degrades
-to the identity and the very same function runs locally.  Because every
+to the identity and the very same function runs locally.  The Pallas
+kernels, which GSPMD cannot split, run on each device's own rows
+(`api.batch_local`, called by the pallas backend).  Because every
 int8 op is exact and rows are independent, the sharded wave is
 bit-identical to the unsharded one.
 

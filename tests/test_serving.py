@@ -272,6 +272,10 @@ def test_sharded_wave_bit_parity_on_8device_mesh():
         assert not meshed.in_sharding.is_fully_replicated  # really split
         for a, b in zip(plain(x), meshed(x)):
             assert np.array_equal(np.asarray(a), np.asarray(b))
+        # the Pallas kernels run per device on their own rows (shard_map)
+        pallas = compile_wave(qnet.with_backend("pallas"), 8, mesh=mesh)
+        for a, b in zip(plain(x), pallas(x)):
+            assert np.array_equal(np.asarray(a), np.asarray(b))
         print("OK")
     """) % SRC
     out = subprocess.run([sys.executable, "-c", script],

@@ -249,6 +249,25 @@ def test_pallas_fallback_counter_and_warn_once():
     assert be.fallbacks[("squash", "approx")] > before[("squash", "approx")]
 
 
+def test_pallas_out_frac_fallback_counted():
+    """The fused routing kernel emits Q0.7 only: a plan whose squash
+    output is edited to Q0.6 takes the oracle loop, counted and warned
+    like the variant fallbacks, and stays bit-identical to the oracle."""
+    qnet, x_q = built()
+    caps = dataclasses.replace(qnet.plan.layers["caps"], squash_out_frac=6)
+    q6 = dataclasses.replace(qnet, plan=dataclasses.replace(
+        qnet.plan, layers={**qnet.plan.layers, "caps": caps}))
+    be = PallasBackend()
+    with pytest.warns(RuntimeWarning, match="falling back"):
+        v_pal = np.asarray(q6.pipeline.forward_q7(
+            q6.qweights, q6.plan, jnp.asarray(x_q), backend=be,
+            rounding=q6.rounding))
+    assert dict(be.fallbacks) == {("routing.out_frac", "Q0.6"): 1}
+    assert be.metrics.counter("pallas.fallback_decisions").total() == 1
+    np.testing.assert_array_equal(
+        v_pal, np.asarray(q6.forward(jnp.asarray(x_q))))
+
+
 def test_registry_warns_once_per_model_and_variant():
     spec = ModelSpec("tiny@pallas", EDGE_TINY, backend="pallas",
                      dataset="uniform", calib_n=4,
