@@ -209,9 +209,10 @@ class PrimaryCaps:
         y = self.conv.fwd_q7(qweights, plan.conv, x, backend=backend,
                              rounding=rounding)
         u = y.reshape(y.shape[0], -1, self.dim)
-        return get_backend(backend).squash_q7(
-            u, in_frac=plan.conv.out_frac, out_frac=plan.squash_out_frac,
-            impl=plan.squash_impl)
+        with jax.named_scope("squash"):
+            return get_backend(backend).squash_q7(
+                u, in_frac=plan.conv.out_frac,
+                out_frac=plan.squash_out_frac, impl=plan.squash_impl)
 
     def fwd_fq(self, params, plan: PrimaryCapsPlan, x, *, rounding="floor"):
         y = self.conv.fwd_fq(params, plan.conv, x, rounding=rounding)
@@ -311,9 +312,11 @@ class CapsuleRouting:
         be = get_backend(backend)
         shift = plan.uhat_shift_per_out if plan.per_out \
             else plan.uhat_shift
-        u_hat = be.uhat_q7(qweights["W"], u, shift=shift,
-                           rounding=rounding)
-        return be.routing_q7(u_hat, plan, rounding=rounding)
+        with jax.named_scope("uhat"):
+            u_hat = be.uhat_q7(qweights["W"], u, shift=shift,
+                               rounding=rounding)
+        with jax.named_scope("routing"):
+            return be.routing_q7(u_hat, plan, rounding=rounding)
 
     @staticmethod
     def _softmax_fq(b, impl: str):

@@ -191,12 +191,14 @@ class CapsPipeline:
         if _health._PROBE is None:                 # hot path untouched
             h = x_q
             for l in self.layers:
-                h = l.fwd_q7(qweights[l.name], plan[l.name], h,
-                             backend=backend, rounding=rounding)
+                with jax.named_scope(l.name):      # trace metadata only
+                    h = l.fwd_q7(qweights[l.name], plan[l.name], h,
+                                 backend=backend, rounding=rounding)
             return h
         h = x_q
         for i, l in enumerate(self.layers):
-            with _health.scope(l.name, index=i, kind=type(l).__name__):
+            with jax.named_scope(l.name), \
+                    _health.scope(l.name, index=i, kind=type(l).__name__):
                 h = l.fwd_q7(qweights[l.name], plan[l.name], h,
                              backend=backend, rounding=rounding)
                 if not _health._is_tracer(h):

@@ -9,10 +9,12 @@ them.  This module is the consumer:
     JSON (dict or path — the exact format `Tracer.write_chrome_trace`
     emits) and produces per-span-name statistics (count / total / mean /
     p50 / p95 / max, self-time vs child-time), the critical path of
-    every `serve.wave`, a queue/compile/execute wall-time breakdown per
-    (model, bucket), and — from the `req_id`/`req_ids` args the serving
-    engine stamps — the reconstructed enqueue -> complete timeline of
-    every request, from the trace alone;
+    every `serve.wave`, a wall-time breakdown per (model, bucket) of
+    queue wait, compiles and the wave's bucket / transfer / dispatch /
+    wait / readback / complete phases, and — from the `req_id` /
+    `req_ids` args the serving engine stamps — the reconstructed
+    enqueue -> complete timeline of every request, from the trace
+    alone;
   * `costmodel_drift(program, measured_rows)` joins
     `EdgeVM.run(profile=rows)` measured rows against
     `costmodel.estimate_program` estimated rows on their shared
@@ -241,13 +243,17 @@ def request_timelines(roots) -> list:
     return sorted(out, key=lambda r: r["req_id"])
 
 
-_WAVE_PHASES = {"serve.bucket": "bucket_s", "serve.compile": "compile_s",
-                "serve.execute": "execute_s", "serve.complete": "complete_s"}
+_WAVE_PHASES = {"serve.bucket": "bucket_s", "serve.transfer": "transfer_s",
+                "serve.dispatch": "dispatch_s", "serve.wait": "wait_s",
+                "serve.readback": "readback_s",
+                "serve.complete": "complete_s"}
 
 
 def wave_breakdown(roots) -> list:
-    """Queue/bucket/compile/execute/complete wall time per (model,
-    bucket): where a serving run's wall clock went, per wave shape."""
+    """Queue wait and each wave phase's wall time per (model, bucket):
+    where a serving run's wall clock went, per wave shape.  `compile_s`
+    is the registry's `serving.compile_wave` spans inside the waves (a
+    cache miss)."""
     agg: dict = {}
     for w in walk(roots):
         if w.name != "serve.wave":
@@ -255,9 +261,9 @@ def wave_breakdown(roots) -> list:
         key = (w.args.get("model"), w.args.get("bucket"))
         a = agg.setdefault(key, {"model": key[0], "bucket": key[1],
                                  "waves": 0, "images": 0, "wave_s": 0.0,
-                                 "queue_s": 0.0, "bucket_s": 0.0,
-                                 "compile_s": 0.0, "execute_s": 0.0,
-                                 "complete_s": 0.0})
+                                 "queue_s": 0.0, "compile_s": 0.0,
+                                 **dict.fromkeys(_WAVE_PHASES.values(),
+                                                 0.0)})
         a["waves"] += 1
         a["images"] += int(w.args.get("n_real") or 0)
         a["wave_s"] += w.dur_s
@@ -265,6 +271,8 @@ def wave_breakdown(roots) -> list:
             phase = _WAVE_PHASES.get(c.name)
             if phase is not None:
                 a[phase] += c.dur_s
+        a["compile_s"] += sum(n.dur_s for n in walk(w.children)
+                              if n.name == "serving.compile_wave")
     for r in request_timelines(roots):
         key = (r.get("model"), r.get("bucket"))
         if key in agg and "queue_s" in agg[key] and "e2e_s" in r:
@@ -318,15 +326,14 @@ def format_analysis(report: dict) -> str:
                          f"{_ms(w['dur_s'])}ms: {path}")
     if report["breakdown"]:
         lines.append("breakdown per (model, bucket), wall ms:")
-        lines.append(f"  {'model':<16}{'bucket':>7}{'waves':>6}"
-                     f"{'imgs':>5}{'queue':>9}{'compile':>9}"
-                     f"{'execute':>9}{'complete':>9}")
+        cols = ("queue", "compile", "bucket", "transfer", "dispatch",
+                "wait", "readback", "complete")
+        lines.append(f"  {'model':<16}{'bucket':>7}{'waves':>6}{'imgs':>5}"
+                     + "".join(f"{c:>9}" for c in cols))
         for b in report["breakdown"]:
             lines.append(f"  {str(b['model']):<16}{str(b['bucket']):>7}"
                          f"{b['waves']:>6}{b['images']:>5}"
-                         f"{_ms(b['queue_s']):>9}{_ms(b['compile_s']):>9}"
-                         f"{_ms(b['execute_s']):>9}"
-                         f"{_ms(b['complete_s']):>9}")
+                         + "".join(f"{_ms(b[c + '_s']):>9}" for c in cols))
     reqs = [r for r in report["requests"] if "e2e_s" in r]
     if reqs:
         e2e = sorted(r["e2e_s"] for r in reqs)

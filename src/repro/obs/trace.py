@@ -13,6 +13,14 @@ the clock or allocating — tracing is free when it is off, which is what
 lets the serving/VM hot paths stay instrumented permanently (traced and
 untraced runs are pinned bit-identical in tests/test_obs.py).
 
+Every span a `Tracer` records is also entered as a
+`jax.profiler.TraceAnnotation` of the same name, so while a profile is
+being captured (`jax.profiler.start_trace`) the span lands on the
+profiler's host plane too, on the same clock as the device's
+operations; an idle gap of the device can then be matched to the span
+the host was in.  Without a capture an annotation costs under a
+microsecond.  The tracer's own copy stays on its injectable clock.
+
 Export is the Chrome trace-event JSON format ("complete" `ph:"X"`
 events, microsecond timestamps), loadable in chrome://tracing or
 Perfetto:
@@ -38,7 +46,8 @@ class Span:
     t1.  `dur_s` is None while the span is still open.
     """
 
-    __slots__ = ("name", "args", "t0", "t1", "children", "_tracer")
+    __slots__ = ("name", "args", "t0", "t1", "children", "_tracer",
+                 "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, args: dict):
         self.name = name
@@ -47,6 +56,7 @@ class Span:
         self.t1: float | None = None
         self.children: list = []
         self._tracer = tracer
+        self._annotation = None
 
     @property
     def dur_s(self) -> float | None:
@@ -63,11 +73,16 @@ class Span:
         t = self._tracer
         (t._stack[-1].children if t._stack else t.roots).append(self)
         t._stack.append(self)
+        # the profiler's copy opens first and closes last, so it holds
+        # the tracer's interval (and its nesting) on the host plane
+        self._annotation = t._annotation(self.name)
+        self._annotation.__enter__()
         self.t0 = t.clock()
         return self
 
     def __exit__(self, *exc) -> bool:
         self.t1 = self._tracer.clock()
+        self._annotation.__exit__(*exc)
         # tolerate exception-driven unwinds that skipped inner __exit__s
         stack = self._tracer._stack
         while stack and stack.pop() is not self:
@@ -110,12 +125,15 @@ NULL_SPAN = _NullSpan()
 
 class Tracer:
     """Records spans into a forest; not thread-safe by design (the
-    serving engine and trainer are single-threaded drivers)."""
+    serving engine and the trainer each run on one thread).  Each span
+    is bridged to the JAX profiler's host plane (module docstring)."""
 
     def __init__(self, clock=time.perf_counter):
+        from jax.profiler import TraceAnnotation
         self.clock = clock
         self.roots: list = []
         self._stack: list = []
+        self._annotation = TraceAnnotation
 
     # ------------------------------------------------------------------
     # recording

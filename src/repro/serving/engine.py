@@ -26,6 +26,7 @@ import collections
 import dataclasses
 import time
 
+import jax
 import numpy as np
 
 from repro import obs
@@ -130,6 +131,7 @@ class CapsServeEngine:
         model_id = self._queue[0].model_id
         with self._span("serve.wave", model=model_id,
                         wave=self._next_wave) as wave_span:
+            t_start = self.clock()
             with self._span("serve.bucket"):
                 wave: list = []
                 for r in self._queue:            # peek, don't pop yet
@@ -143,26 +145,31 @@ class CapsServeEngine:
                     np.float32)
                 for i, r in enumerate(wave):
                     x[i] = r.image
-            # the analyzer reconstructs per-request timelines by joining
-            # enqueue req_id against this membership (comma-joined: span
-            # args are scalar-or-string in the Chrome export)
-            req_ids = ",".join(str(r.rid) for r in wave)
-            wave_span.note(bucket=bucket, n_real=len(wave),
-                           req_ids=req_ids)
+            if wave_span is not obs.NULL_SPAN:
+                # the analyzer reconstructs per-request timelines by
+                # joining enqueue req_id against this membership
+                # (comma-joined: span args are scalar-or-string in the
+                # Chrome export)
+                wave_span.note(bucket=bucket, n_real=len(wave),
+                               req_ids=",".join(str(r.rid) for r in wave))
 
-            # registry adds serving.compile_wave / serving.ptq_build
-            # child spans on a cache miss; a hit is just the lookup
-            with self._span("serve.compile", bucket=bucket):
-                exe = self.registry.executable(model_id, bucket)
-            with self._span("serve.execute", bucket=bucket,
-                            n_real=len(wave)):
-                t0 = self.clock()
-                v_q, lengths, pred = exe(x)
-                # host conversion doubles as block_until_ready
-                v_q, lengths, pred = (np.asarray(v_q), np.asarray(lengths),
-                                      np.asarray(pred))
-                t_done = self.clock()
-            with self._span("serve.complete", req_ids=req_ids):
+            # a cache miss records serving.compile_wave (and
+            # serving.ptq_build) inside the wave; a hit is a dict lookup
+            exe = self.registry.executable(model_id, bucket)
+            t0 = self.clock()
+            with self._span("serve.transfer", bucket=bucket):
+                x = exe.put(x)
+            t1 = self.clock()
+            with self._span("serve.dispatch"):
+                out = exe(x)                     # returns once queued
+            t2 = self.clock()
+            with self._span("serve.wait"):
+                jax.block_until_ready(out)       # host blocked on device
+            t3 = self.clock()
+            with self._span("serve.readback"):
+                v_q, lengths, pred = (np.asarray(o) for o in out)
+            t_done = self.clock()
+            with self._span("serve.complete"):
                 # only now is the wave irrevocably served: a raising
                 # executable leaves the queue intact so the requests can
                 # be retried
@@ -177,10 +184,14 @@ class CapsServeEngine:
                                    bucket=bucket,
                                    latency_s=t_done - r.t_enq)
                         for i, r in enumerate(wave)]
+                wait_s = t3 - t2
                 self.metrics.record_wave(
                     bucket=bucket, n_real=len(wave), exec_s=t_done - t0,
                     t_done=t_done,
-                    latencies_s=[c.latency_s for c in done])
+                    latencies_s=[c.latency_s for c in done],
+                    transfer_s=t1 - t0, dispatch_s=t2 - t1, wait_s=wait_s,
+                    readback_s=t_done - t3,
+                    host_s=self.clock() - t_start - wait_s)
         return done
 
     def drain(self) -> list:

@@ -31,6 +31,7 @@ class ServeMetrics:
     def __init__(self, registry: obs.MetricsRegistry | None = None):
         self.latencies_s: list = []          # one per completed request
         self.waves: list = []                # dicts: bucket/n_real/exec_s
+                                             # + record_wave's phases
         self.queue_depths: list = []         # depth sampled at each submit
         self.t_first_submit: float | None = None
         self.t_last_done: float | None = None
@@ -58,9 +59,19 @@ class ServeMetrics:
             self._g_queue.set(queue_depth)
 
     def record_wave(self, *, bucket: int, n_real: int, exec_s: float,
-                    t_done: float, latencies_s) -> None:
+                    t_done: float, latencies_s, transfer_s: float = 0.0,
+                    dispatch_s: float = 0.0, wait_s: float = 0.0,
+                    readback_s: float = 0.0, host_s: float = 0.0) -> None:
+        """One served wave.  `exec_s` runs from the host->device copy to
+        the outputs on the host; transfer, dispatch, wait and readback
+        split it, and `host_s` is the whole wave less `wait_s`: the
+        host's own time in it.  The engine clocks them on every wave,
+        traced or not."""
         self.waves.append(
-            {"bucket": bucket, "n_real": n_real, "exec_s": exec_s})
+            {"bucket": bucket, "n_real": n_real, "exec_s": exec_s,
+             "transfer_s": transfer_s, "dispatch_s": dispatch_s,
+             "wait_s": wait_s, "readback_s": readback_s,
+             "host_s": host_s})
         self.latencies_s.extend(latencies_s)
         self.t_last_done = t_done
         if self.registry is not None:

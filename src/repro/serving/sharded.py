@@ -50,7 +50,9 @@ class CompiledWave:
     bucket: int
     input_shape: tuple               # (bucket, H, W, C)
 
-    def __call__(self, x):
+    def put(self, x) -> jax.Array:
+        """The host->device copy of a padded batch, placed with the
+        executable's input sharding (the default device off-mesh)."""
         x = jnp.asarray(x, jnp.float32)
         if x.shape != self.input_shape:
             raise ValueError(
@@ -58,6 +60,14 @@ class CompiledWave:
                 f"got {x.shape}")
         if self.in_sharding is not None:
             x = jax.device_put(x, self.in_sharding)
+        return x
+
+    def __call__(self, x):
+        """Dispatch the wave on `x`, a host batch or the device array
+        `put` returned; the outputs are device arrays, possibly still
+        being computed."""
+        if not isinstance(x, jax.Array):
+            x = self.put(x)
         return self.compiled(x)
 
 

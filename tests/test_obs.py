@@ -13,7 +13,10 @@ Pins, in order:
     (None / "no completed requests"), never formatted NaNs, while the
     low-level accessors keep their pinned nan-on-empty contract;
   * traced serving is bit-identical to untraced and emits the nested
-    enqueue -> wave -> execute span forest as valid Chrome JSON;
+    enqueue -> wave -> bucket/transfer/dispatch/wait/readback/complete
+    span forest as valid Chrome JSON; under the JAX profiler the same
+    spans land on its host plane; the engine clocks each wave's phases
+    into ServeMetrics whether or not a tracer is installed;
   * EdgeVM with `profile`/`trace`/ambient tracing returns the same bits
     as the bare hot path, for every config x rounding;
   * the static MCU cost model reproduces the paper's four latencies
@@ -105,16 +108,16 @@ def test_span_exception_unwind_keeps_stack_sane():
 def test_chrome_trace_export(tmp_path):
     tr = obs.Tracer(clock=FakeClock())
     with tr.span("serve.wave", bucket=4):
-        with tr.span("serve.execute"):
+        with tr.span("serve.wait"):
             pass
     doc = tr.chrome_trace()
     ev = {e["name"]: e for e in doc["traceEvents"]}
-    assert set(ev) == {"serve.wave", "serve.execute"}
+    assert set(ev) == {"serve.wave", "serve.wait"}
     assert all(e["ph"] == "X" for e in ev.values())
-    # fake clock: wave=[1,4], execute=[2,3]; epoch shift -> wave ts=0
+    # fake clock: wave=[1,4], wait=[2,3]; epoch shift -> wave ts=0
     assert ev["serve.wave"]["ts"] == 0.0
     assert ev["serve.wave"]["dur"] == pytest.approx(3e6)
-    assert ev["serve.execute"]["ts"] == pytest.approx(1e6)
+    assert ev["serve.wait"]["ts"] == pytest.approx(1e6)
     assert ev["serve.wave"]["cat"] == "serve"
     assert ev["serve.wave"]["args"] == {"bucket": 4}
     path = tr.write_chrome_trace(tmp_path / "t" / "trace.json")
@@ -336,21 +339,21 @@ def test_traced_serving_bit_identical_and_nested(edge_tiny_registry,
         assert (b.pred, b.wave, b.bucket) == (t.pred, t.wave, t.bucket)
 
     # span forest: enqueue roots + one wave root per wave, with the
-    # bucket/compile/execute/complete pipeline nested inside
+    # wave's phases nested inside
     assert len(tracer.find("serve.enqueue")) == 6
     waves = [r for r in tracer.roots if r.name == "serve.wave"]
     assert len(waves) == len({c.wave for c in traced}) == 2
     for w in waves:
         kids = [c.name for c in w.children]
-        assert kids == ["serve.bucket", "serve.compile", "serve.execute",
-                        "serve.complete"]
+        assert kids == ["serve.bucket", "serve.transfer", "serve.dispatch",
+                        "serve.wait", "serve.readback", "serve.complete"]
         assert w.t0 <= w.children[0].t0 and w.children[-1].t1 <= w.t1
     # valid Chrome JSON with the nesting visible as containment
     path = tracer.write_chrome_trace(tmp_path / "trace.json")
     doc = json.loads(path.read_text())
     names = [e["name"] for e in doc["traceEvents"]]
     assert names.count("serve.wave") == 2
-    assert names.count("serve.execute") == 2
+    assert names.count("serve.wait") == 2
     for e in doc["traceEvents"]:
         assert e["ph"] == "X" and e["dur"] >= 0
 
@@ -371,7 +374,95 @@ def test_ambient_tracer_reaches_engine_and_ptq(edge_tiny_registry):
     assert tracer.find("ptq.calibrate")          # pipeline spans
     assert tracer.find("serving.compile_wave")
     wave = tracer.find("serve.wave")[0]
-    assert wave.find("serve.execute")            # nested under the wave
+    assert wave.find("serve.wait")               # nested under the wave
+    # a cache miss compiles inside the wave that needed it
+    assert wave.find("serving.compile_wave")
+
+
+@pytest.mark.parametrize("traced", [False, True], ids=["untraced", "traced"])
+def test_engine_clocks_wave_phases(edge_tiny_registry, traced):
+    """The engine reads its own clock at each phase boundary of every
+    wave, so ServeMetrics keeps the phases with or without a tracer.
+    Read k returns 1 + 2 + ... + k, so each phase has its own length."""
+    reads = iter(np.cumsum(np.arange(1, 100)).tolist())
+    tracer = obs.Tracer(clock=FakeClock()) if traced else None
+    engine = CapsServeEngine(edge_tiny_registry, buckets=(4,),
+                             clock=lambda: float(next(reads)),
+                             tracer=tracer)
+    engine.warmup("tiny")
+    rng = np.random.default_rng(9)
+    engine.submit_many(rng.uniform(0, 1, (2,) + tuple(
+        EDGE_TINY.input_shape)).astype(np.float32), "tiny")   # reads 1, 3
+    done = engine.step()
+    # wave start 6, transfer 10 -> 15, dispatch -> 21, wait -> 28,
+    # readback -> 36 (done), bookkeeping -> 45
+    (w,) = engine.metrics.waves
+    assert w == {"bucket": 4, "n_real": 2, "exec_s": 26.0,
+                 "transfer_s": 5.0, "dispatch_s": 6.0, "wait_s": 7.0,
+                 "readback_s": 8.0, "host_s": 45.0 - 6.0 - 7.0}
+    assert [c.latency_s for c in done] == [35.0, 33.0]
+    assert engine.metrics.t_last_done == 36.0
+
+
+def test_profiler_host_plane_holds_the_tracer_spans(edge_tiny_registry,
+                                                    tmp_path):
+    """Under the JAX profiler every serve.* span of an installed Tracer
+    is also a host-plane event of the same name, nesting and length."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    engine = CapsServeEngine(edge_tiny_registry, buckets=(4,))
+    engine.warmup("tiny")
+    rng = np.random.default_rng(10)
+    images = rng.uniform(0, 1, (8,) + tuple(EDGE_TINY.input_shape)) \
+        .astype(np.float32)
+    tracer = obs.Tracer()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with obs.tracing(tracer):
+            engine.submit_many(images, "tiny")
+            assert len(engine.drain()) == 8
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                        recursive=True)
+    events: dict = {}               # name -> [(start_ns, end_ns)] in order
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("serve."):
+                        events.setdefault(e.name, []).append(
+                            (e.start_ns, e.start_ns + e.duration_ns))
+    for ivs in events.values():
+        ivs.sort()
+
+    waves = [r for r in tracer.roots if r.name == "serve.wave"]
+    assert len(waves) == 2
+    seen: dict = {}
+
+    def match(span, parent_iv):
+        k = seen.get(span.name, 0)
+        seen[span.name] = k + 1
+        start, end = events[span.name][k]
+        if parent_iv is not None:
+            assert parent_iv[0] <= start and end <= parent_iv[1], span.name
+        dur_s = (end - start) / 1e9
+        assert abs(dur_s - span.dur_s) <= max(2e-4, 0.2 * span.dur_s), \
+            (span.name, dur_s, span.dur_s)
+        for c in span.children:
+            match(c, (start, end))
+
+    for root in tracer.roots:
+        match(root, None)
+    assert seen == {name: len(ivs) for name, ivs in events.items()}
+    assert set(seen) == {"serve.enqueue", "serve.wave", "serve.bucket",
+                         "serve.transfer", "serve.dispatch", "serve.wait",
+                         "serve.readback", "serve.complete"}
 
 
 # ---------------------------------------------------------------------------

@@ -3,7 +3,7 @@
 
 Pins, in order:
   * the analyzer under a fake clock: per-span stats, self vs child
-    time, wave critical paths, the queue/compile/execute breakdown and
+    time, wave critical paths, the per-phase wave breakdown and
     the reconstructed per-request timelines — all EXACT, and bit-equal
     whether the source is the live Tracer or its own Chrome export;
   * the repo-wide tiny-sample percentile policy on obs.Histogram:
@@ -51,9 +51,10 @@ def _fake_serve_trace() -> obs.Tracer:
     read advances 1s), so every analyzer number is exact:
 
       enqueue#0 [1,2]  enqueue#1 [3,4]
-      wave [5,16]: bucket [6,7]  compile [8,9]
-                   execute [10,13] > edgevm.run [11,12]
-                   complete [14,15]
+      wave [5,22]: bucket [6,7]  serving.compile_wave [8,9]
+                   transfer [10,11]  dispatch [12,13]
+                   wait [14,17] > edgevm.run [15,16]
+                   readback [18,19]  complete [20,21]
     """
     tr = obs.Tracer(clock=FakeClock())
     with tr.span("serve.enqueue", model="m", req_id=0):
@@ -63,12 +64,18 @@ def _fake_serve_trace() -> obs.Tracer:
     with tr.span("serve.wave", wave=0, model="m") as w:
         with tr.span("serve.bucket"):
             pass
-        with tr.span("serve.compile"):
+        with tr.span("serving.compile_wave", model="m", bucket=4):
             pass
-        with tr.span("serve.execute"):
+        with tr.span("serve.transfer"):
+            pass
+        with tr.span("serve.dispatch"):
+            pass
+        with tr.span("serve.wait"):
             with tr.span("edgevm.run"):
                 pass
-        with tr.span("serve.complete", req_ids="0,1"):
+        with tr.span("serve.readback"):
+            pass
+        with tr.span("serve.complete"):
             pass
         w.note(bucket=4, n_real=2, req_ids="0,1")
     return tr
@@ -79,18 +86,18 @@ def _fake_serve_trace() -> obs.Tracer:
 # ---------------------------------------------------------------------------
 def test_span_stats_exact_under_fake_clock():
     report = analyze.analyze(_fake_serve_trace())
-    assert report["span_count"] == 8
+    assert report["span_count"] == 11
     s = report["spans"]
     # epoch-normalized: the first enqueue starts at 0.0
     assert s["serve.enqueue"] == {
         "count": 2, "total_s": 2.0, "mean_s": 1.0, "p50_s": 1.0,
         "p95_s": 1.0, "max_s": 1.0, "self_s": 2.0}
-    # wave [4,15]: dur 11, children 1+1+3+1 -> self 5
-    assert s["serve.wave"]["total_s"] == 11.0
-    assert s["serve.wave"]["self_s"] == 5.0
-    # execute [9,12] contains edgevm.run [10,11] -> self 2
-    assert s["serve.execute"]["total_s"] == 3.0
-    assert s["serve.execute"]["self_s"] == 2.0
+    # wave [4,21]: dur 17, children 1+1+1+1+3+1+1 -> self 8
+    assert s["serve.wave"]["total_s"] == 17.0
+    assert s["serve.wave"]["self_s"] == 8.0
+    # wait [13,16] contains edgevm.run [14,15] -> self 2
+    assert s["serve.wait"]["total_s"] == 3.0
+    assert s["serve.wait"]["self_s"] == 2.0
     assert s["edgevm.run"]["self_s"] == 1.0
 
 
@@ -100,22 +107,22 @@ def test_wave_critical_path_and_summary():
     assert (w["wave"], w["model"], w["bucket"], w["n_real"]) \
         == (0, "m", 4, 2)
     assert w["req_ids"] == [0, 1]
-    assert w["dur_s"] == 11.0
-    # execute (3s) dominates bucket/compile/complete (1s each)
+    assert w["dur_s"] == 17.0
+    # wait (3s) dominates the other phases (1s each)
     assert [p["name"] for p in w["critical_path"]] \
-        == ["serve.wave", "serve.execute", "edgevm.run"]
-    assert [p["dur_s"] for p in w["critical_path"]] == [11.0, 3.0, 1.0]
+        == ["serve.wave", "serve.wait", "edgevm.run"]
+    assert [p["dur_s"] for p in w["critical_path"]] == [17.0, 3.0, 1.0]
 
 
 def test_request_timelines_exact():
     report = analyze.analyze(_fake_serve_trace())
     r0, r1 = report["requests"]
-    # rid 0: enqueued [0,1], wave opens at 4, last complete exits at 14
+    # rid 0: enqueued [0,1], wave opens at 4, last complete exits at 20
     assert (r0["req_id"], r0["wave"], r0["bucket"]) == (0, 0, 4)
-    assert (r0["t_enq"], r0["t_done"]) == (0.0, 14.0)
-    assert (r0["e2e_s"], r0["queue_s"]) == (14.0, 3.0)
+    assert (r0["t_enq"], r0["t_done"]) == (0.0, 20.0)
+    assert (r0["e2e_s"], r0["queue_s"]) == (20.0, 3.0)
     # rid 1: enqueued [2,3] -> shorter queue, same completion
-    assert (r1["t_enq"], r1["e2e_s"], r1["queue_s"]) == (2.0, 12.0, 1.0)
+    assert (r1["t_enq"], r1["e2e_s"], r1["queue_s"]) == (2.0, 18.0, 1.0)
 
 
 def test_wave_breakdown_exact():
@@ -123,9 +130,10 @@ def test_wave_breakdown_exact():
     (b,) = report["breakdown"]
     assert (b["model"], b["bucket"], b["waves"], b["images"]) \
         == ("m", 4, 1, 2)
-    assert b["wave_s"] == 11.0
-    assert (b["bucket_s"], b["compile_s"], b["execute_s"],
-            b["complete_s"]) == (1.0, 1.0, 3.0, 1.0)
+    assert b["wave_s"] == 17.0
+    assert (b["bucket_s"], b["compile_s"], b["transfer_s"],
+            b["dispatch_s"], b["wait_s"], b["readback_s"],
+            b["complete_s"]) == (1.0, 1.0, 1.0, 1.0, 3.0, 1.0, 1.0)
     assert b["queue_s"] == 4.0                   # 3.0 + 1.0
 
 
@@ -149,8 +157,8 @@ def test_load_trace_rejects_garbage():
 def test_format_analysis_renders_every_block():
     report = analyze.analyze(_fake_serve_trace())
     text = analyze.format_analysis(report)
-    assert "8 spans" in text
-    assert "serve.wave > serve.execute > edgevm.run" in text
+    assert "11 spans" in text
+    assert "serve.wave > serve.wait > edgevm.run" in text
     assert "breakdown per (model, bucket)" in text
     assert "requests: 2 reconstructed" in text
 
@@ -407,11 +415,11 @@ def test_analyze_cli(tmp_path, capsys):
          "run": reg.snapshot(), "serve_summary": None}))
     assert analyze.main([str(path), "--metrics", str(metrics)]) == 0
     out = capsys.readouterr().out
-    assert "serve.wave > serve.execute" in out
+    assert "serve.wave > serve.wait" in out
     assert "serve.requests_total (counter): 2" in out
     assert analyze.main([str(path), "--json"]) == 0
     report = json.loads(capsys.readouterr().out)
-    assert report["span_count"] == 8
+    assert report["span_count"] == 11
 
 
 def test_baseline_cli_compare_ok(capsys):

@@ -11,6 +11,8 @@ The topology is described inside a module-scoped fixture, never at
 import: only one process may hold the TPU library, and every xdist
 worker imports every test file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -94,7 +96,22 @@ def test_pallas_wave_compiles_cifar10(one_chip, cifar10_pallas,
     # in interpret mode; the wave is compiled for the TPU
     monkeypatch.setattr(ops, "default_interpret", lambda: False)
     compiled = jax.jit(wave_fn(cifar10_pallas)).lower(x).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    # each layer's named scope reaches the compiled ops' metadata, with
+    # the capsule phases inside the capsule layers
+    op_names = set(re.findall(r'op_name="([^"]*)"', hlo))
+    scopes = [f"/{l.name}/" for l in cifar10_pallas.pipeline.layers]
+    pcap, caps = (l.name for l in cifar10_pallas.pipeline.layers[-2:])
+    scopes += [f"/{pcap}/squash/", f"/{caps}/uhat/", f"/{caps}/routing/"]
+    for scope in scopes:
+        assert any(scope in n for n in op_names), scope
+    # the kernels keep their names, which the benchmark's op classes
+    # are keyed on
+    kernels = re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target="
+                         r'"tpu_custom_call"', hlo)
+    assert any("routing" in k for k in kernels), kernels
+    assert any("squash" in k for k in kernels), kernels
 
 
 def test_sharded_pallas_wave_compiles_on_4_chips(topo, cifar10_pallas,
