@@ -58,18 +58,24 @@ def bmm_q7(a, b, shift: int, rounding: str = "floor",
 
 def squash_q7(s, in_frac: int, out_frac: int = 7,
               interpret: bool | None = None):
-    """[..., D] int8 -> int8 (paper Eq. 8); rows flattened and padded."""
+    """[..., D] int8 -> int8 (paper Eq. 8), laid out lane-dense.
+
+    The capsules are flattened to rows `[R, D]`, padded with zero rows
+    (a zero capsule squashes to zero) to whole blocks of 128, and
+    transposed to the kernel's `[D, R/128, 128]`: capsules on the lanes,
+    D on a leading axis.  The result is transposed back to `[..., D]`."""
     interpret = default_interpret() if interpret is None else interpret
     lead, D = s.shape[:-1], s.shape[-1]
     s2 = s.reshape(-1, D)
     R = s2.shape[0]
-    br = min(256, R)
-    pad = (-R) % br
+    rb, grid = _squash.tiling(-(-R // _squash.LANES), D)
+    pad = rb * grid * _squash.LANES - R
     if pad:
         s2 = jnp.pad(s2, ((0, pad), (0, 0)))
-    out = _squash.squash_q7_pallas(s2, in_frac=in_frac, out_frac=out_frac,
-                                   block_rows=br, interpret=interpret)
-    return out[:R].reshape(lead + (D,))
+    st = s2.T.reshape(D, rb * grid, _squash.LANES)
+    out = _squash.squash_q7_pallas(st, in_frac=in_frac, out_frac=out_frac,
+                                   interpret=interpret)
+    return out.reshape(D, -1).T[:R].reshape(lead + (D,))
 
 
 def squash_float(s, interpret: bool | None = None):

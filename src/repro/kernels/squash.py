@@ -1,11 +1,14 @@
 """Pallas TPU kernel: integer squash activation (paper Eq. 8 + Alg. 4).
 
-Row-blocked over the capsule axis: each grid step loads a [block_rows, D]
-tile of int8 capsule vectors into VMEM, computes the int32 sum of squares,
-runs the fixed-iteration Newton-Raphson integer sqrt on the VPU, applies
-the guarded power-of-two ratio, and writes int8 back.  D (the capsule
-dimension, 4-8 in the paper) is far below the 128-lane width; the ops.py
-wrapper keeps rows as the lane dimension by blocking many rows per tile.
+Lane-dense: the kernel sees the capsules as `[D, rows, 128]` int8, each
+capsule on one of the 128 lanes and its D components (4-8 in the paper)
+on the leading axis; `ops.squash_q7` lays `[..., D]` out so, padding
+with zero capsules.  Each grid step loads a `(D, rb, 128)` tile
+into VMEM, sums the squares over the leading axis (plain vector adds),
+runs the fixed-iteration Newton-Raphson integer sqrt and the guarded
+power-of-two ratio on dense `[rb, 128]` int32 tiles, and writes
+int8 back in the same layout.  `tiling` picks the block from the number
+of capsules and D.
 """
 from __future__ import annotations
 
@@ -31,9 +34,29 @@ def _isqrt(n):
     return jnp.where(n <= 1, n, x)
 
 
+LANES = 128
+_SUBLANES_I8 = 32           # int8 tile: (32, 128)
+_BLOCK_BYTES = 64 * 1024    # int8 input bytes per grid step
+
+
+def tiling(rows: int, D: int) -> tuple[int, int]:
+    """(rb, grid) for `rows` rows of 128 capsules of D components.
+
+    One block when the rows fit `_BLOCK_BYTES`; else the fewest equal
+    blocks that do, each a whole number of int8 tiles.  The caller pads
+    the rows to `rb * grid`."""
+    cap = max(_SUBLANES_I8, _BLOCK_BYTES // (D * LANES)
+              // _SUBLANES_I8 * _SUBLANES_I8)
+    if rows <= cap:
+        return rows, 1
+    grid = pl.cdiv(rows, cap)
+    rb = pl.cdiv(pl.cdiv(rows, grid), _SUBLANES_I8) * _SUBLANES_I8
+    return rb, grid
+
+
 def _squash_kernel(s_ref, o_ref, *, in_frac: int, out_frac: int):
-    s = s_ref[...].astype(jnp.int32)
-    Q = jnp.sum(s * s, axis=-1, keepdims=True)
+    s = s_ref[...].astype(jnp.int32)            # [D, rb, 128]
+    Q = jnp.sum(s * s, axis=0, keepdims=True)   # [1, rb, 128]
     S = _isqrt(Q)
     P = SQUASH_GUARD_BITS
     shift = out_frac - in_frac + P
@@ -46,20 +69,23 @@ def _squash_kernel(s_ref, o_ref, *, in_frac: int, out_frac: int):
 
 
 @functools.partial(jax.jit, static_argnames=("in_frac", "out_frac",
-                                             "block_rows", "interpret"))
+                                             "interpret"))
 def squash_q7_pallas(s, *, in_frac: int, out_frac: int = 7,
-                     block_rows: int = 256, interpret: bool):
-    """s int8 [R, D] -> int8 [R, D] (rows padded by the ops wrapper)."""
-    R, D = s.shape
-    br = min(block_rows, R)
-    assert R % br == 0
+                     interpret: bool):
+    """s int8 [D, rows, 128] -> int8 [D, rows, 128], one capsule per lane
+    (rows padded by the ops wrapper to `tiling`'s blocks)."""
+    D, rows, lanes = s.shape
+    assert lanes == LANES
+    rb, grid = tiling(rows, D)
+    assert rows == rb * grid
+    spec = pl.BlockSpec((D, rb, LANES), lambda i: (0, i, 0))
     return pl.pallas_call(
         functools.partial(_squash_kernel, in_frac=in_frac,
                           out_frac=out_frac),
-        grid=(R // br,),
-        in_specs=[pl.BlockSpec((br, D), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((br, D), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((R, D), jnp.int8),
+        grid=(grid,),
+        in_specs=[spec],
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct(s.shape, jnp.int8),
         interpret=interpret,
     )(s)
 

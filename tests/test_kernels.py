@@ -7,6 +7,8 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
+from repro.nn.config import CIFAR10, MNIST, SMALLNORB
+from repro.serving import EDGE_TINY
 
 RNG = np.random.default_rng(42)
 
@@ -47,7 +49,10 @@ def test_bmm_q7(batch):
     np.testing.assert_array_equal(got, want)
 
 
-@pytest.mark.parametrize("rd", [(100, 4), (1024, 6), (3, 8), (64, 16)])
+# R not a multiple of 128, D in {4, 6, 8, 16}, and R past one block of
+# the lane-dense tiling (several grid steps, padded rows)
+@pytest.mark.parametrize("rd", [(100, 4), (1024, 6), (3, 8), (64, 16),
+                                (129, 8), (4101, 16), (33000, 4)])
 @pytest.mark.parametrize("in_frac", [3, 5, 7, 9])
 def test_squash_q7_exact(rd, in_frac):
     R, D = rd
@@ -57,8 +62,21 @@ def test_squash_q7_exact(rd, in_frac):
     np.testing.assert_array_equal(got, want)
 
 
-def test_squash_q7_batched_shape():
-    s = i8((2, 7, 11, 4))
+# the primary capsules a served wave squashes: (bucket, I, D) for each
+# geometry at buckets 1 and 64
+SERVED_CAPS = {f"{name}-b{b}": (b, cfg.num_input_caps, cfg.pcap_dim)
+               for name, cfg in [("mnist", MNIST), ("smallnorb", SMALLNORB),
+                                 ("cifar10", CIFAR10),
+                                 ("edge_tiny", EDGE_TINY)]
+               for b in (1, 64)}
+
+
+@pytest.mark.parametrize("fill", [None, -128, 127, 0],
+                         ids=["random", "min", "max", "zero"])
+@pytest.mark.parametrize("shape", [(2, 7, 11, 4), *SERVED_CAPS.values()],
+                         ids=["2x7x11x4", *SERVED_CAPS])
+def test_squash_q7_batched_shape(shape, fill):
+    s = i8(shape) if fill is None else jnp.full(shape, fill, jnp.int8)
     got = ops.squash_q7(s, in_frac=5)
     want = ref.squash_q7(s, in_frac=5)
     assert got.shape == s.shape

@@ -77,13 +77,22 @@ def test_routing_kernel_compiles(one_chip, geometry, batch):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("rows_dim", [(10, 6), (256, 4), (64 * 1024, 4)])
+@pytest.mark.parametrize("rows_dim", [(10, 6), (256, 4), (64, 4),
+                                      (1024, 4), (64 * 64, 4),
+                                      (64 * 1024, 4)])
 def test_squash_kernel_compiles(one_chip, rows_dim):
     s = jax.ShapeDtypeStruct(rows_dim, jnp.int8, sharding=one_chip)
     compiled = jax.jit(
         lambda x: ops.squash_q7(x, in_frac=5, interpret=False)
     ).lower(s).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    # lane-dense: the kernel's operand has the capsules on its minor
+    # (lane) dimension, 128 wide, and not the D components
+    (dims, layout), = re.findall(
+        r"operand_layout_constraints=\{s8\[([\d,]+)\]\{([\d,]+)\}\}", hlo)
+    minor = int(layout.split(",")[0])
+    assert int(dims.split(",")[minor]) == 128 != rows_dim[-1]
 
 
 def test_pallas_wave_compiles_cifar10(one_chip, cifar10_pallas,
