@@ -3,8 +3,11 @@
 A cell names a configuration (its `file`), a traffic mix
 (`traffic/<name>.json`) and, through the metric lists, the readers
 (`metrics/<metric name>.py`, each with `read(ctx) -> float | None`).
-Adding a cell, a configuration, a mix or a metric adds files and
-entries; nothing here changes.
+A configuration names its model module under `"model"`
+(`models/<name>.py`: weights, the program's pipeline, the plain
+reference, work counts and answer shape; see `models/capsnet.py`).
+Adding a cell, a configuration, a model, a mix or a metric adds files
+and entries; nothing here changes.
 """
 from __future__ import annotations
 
@@ -16,6 +19,7 @@ from benchmarks.chip import traffic
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
 METRICS_DIR = pathlib.Path(__file__).resolve().parent / "metrics"
+MODELS_DIR = pathlib.Path(__file__).resolve().parent / "models"
 
 
 def load(root=ROOT) -> dict:
@@ -44,11 +48,36 @@ def resolve(bench: dict, workload: str, root=ROOT) -> dict:
     }
 
 
-def reader(name: str):
-    """The `read` function of metrics/<name>.py."""
-    path = METRICS_DIR / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"benchmarks.chip.metrics.{name.replace('.', '_')}", path)
+def _load(path: pathlib.Path, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def reader(name: str):
+    """The `read` function of metrics/<name>.py."""
+    return _load(METRICS_DIR / f"{name}.py",
+                 f"benchmarks.chip.metrics.{name.replace('.', '_')}").read
+
+
+def model(config: dict):
+    """The module models/<name>.py that the configuration names under
+    "model"; a configuration without one, or naming none of the modules
+    there, is refused.  A model module provides
+
+        make_params(geom, rng)   float32 weights in the program's layout,
+                                 made on the device from the seed
+        pipeline(config)         the program's `CapsPipeline`
+        reference(geom, params, calib, images, bits)
+                                 (v in Q0.7, pred) of the plain integer
+                                 reference (`reference.py`'s primitives)
+        layers(geom)             work per image of each layer (`work.py`)
+        out_shape(geom)          (J, O) of the answers
+    """
+    have = sorted(p.stem for p in MODELS_DIR.glob("*.py"))
+    name = config.get("model")
+    if name not in have:
+        raise ValueError(f"configuration {config.get('name')!r}: \"model\" "
+                         f"must name one of {have}, got {name!r}")
+    return _load(MODELS_DIR / f"{name}.py", f"benchmarks.chip.models.{name}")

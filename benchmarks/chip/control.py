@@ -1,6 +1,6 @@
-"""The control of the comparison that decides `correct`: the reference,
-one precision lower (int4, the configuration states int8), put in the
-program's place.  It has to come out as not correct.
+"""The control of the comparison that decides `correct`: the model
+module's reference, one precision lower (int4, the configuration states
+int8), put in the program's place.  It has to come out as not correct.
 
     python3 benchmarks/chip/control.py --workload mnist_L.backlog \
         --seeds 11,12,13
@@ -22,8 +22,7 @@ if __name__ == "__main__":
 
 import numpy as np  # noqa: E402
 
-from benchmarks.chip import (bench, harness, images, reference,  # noqa: E402
-                             traffic)
+from benchmarks.chip import bench, harness, images, traffic  # noqa: E402
 
 
 CONTROL_BITS = 4            # one precision below the stated int8
@@ -31,14 +30,16 @@ CONTROL_BITS = 4            # one precision below the stated int8
 
 def control_numbers(config: dict, mix: dict, seed: int) -> dict:
     import jax
+    model = bench.model(config)
     g = config["geometry"]
     rngs = traffic.streams(seed)
-    params = jax.device_get(harness.make_params(g, rngs["weights"]))
-    calib = images.make_images(config["images"], config["calib_n"],
-                               rngs["calib"])
-    pool = images.make_images(config["images"], mix["pool"], rngs["pool"])
-    v8, p8 = reference.reference(g, params, calib, pool, 8)
-    v, p = reference.reference(g, params, calib, pool, CONTROL_BITS)
+    params = jax.device_get(model.make_params(g, rngs["weights"]))
+    calib = images.make_images(config["images"], g["input_shape"],
+                               config["calib_n"], rngs["calib"])
+    pool = images.make_images(config["images"], g["input_shape"],
+                              mix["pool"], rngs["pool"])
+    v8, p8 = model.reference(g, params, calib, pool, 8)
+    v, p = model.reference(g, params, calib, pool, CONTROL_BITS)
     return {"vq_mismatch": int(np.sum(v != v8)),
             "pred_mismatch": int(np.sum(p != p8)),
             "answers": int(len(pool))}

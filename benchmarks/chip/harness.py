@@ -2,7 +2,8 @@
 path, warm up, drive the traffic for a timed window, then check every
 answer served against the plain reference.
 
-    build_cell    weights from the seed (one jitted call on the device),
+    build_cell    the configuration's model module (`bench.model`),
+                  weights from the seed (one jitted call on the device),
                   post-training quantization by the program
                   (`CapsPipeline.quantize`), `ModelRegistry.install`,
                   `CapsServeEngine`, and the cell's buckets compiled and
@@ -10,8 +11,8 @@ answer served against the plain reference.
     drive         the traffic loop (closed backlog or open Poisson) for
                   the window, then the requests still queued served to
                   the end (they are due, so they are checked);
-    check         `reference.reference` over the images served, compared
-                  with every answer the program gave.
+    check         the model module's reference over the images served,
+                  compared with every answer the program gave.
 
 The program is used as a user would use it; nothing here reaches into
 its internals beyond the counters it exposes (`registry.compile_count`,
@@ -26,48 +27,18 @@ import time
 
 import numpy as np
 
-from benchmarks.chip import images, reference, traffic as tr, work
+from benchmarks.chip import bench, images, traffic as tr
 
 # how long after the window's close the queued requests may take to be
 # served before the rest count as never served
 DRAIN_LIMIT_S = 60.0
 
 
-def make_params(geom: dict, rng: np.random.Generator) -> dict:
-    """Float32 weights, in the program's parameter layout, made on the
-    device from the seed in one jitted call."""
-    import jax
-    import jax.numpy as jnp
-    key = jax.random.key(int(rng.integers(0, 2 ** 32)))
-    specs = reference.conv_specs(geom)
-    filters = list(geom["conv_filters"]) + [geom["pcap_caps"]
-                                            * geom["pcap_dim"]]
-    kernels = list(geom["conv_kernels"]) + [geom["pcap_kernel"]]
-    W_shape = (geom["num_classes"], work.input_caps(geom), geom["caps_dim"],
-               geom["pcap_dim"])
-
-    def init(key):
-        ks = jax.random.split(key, 2 * len(specs) + 1)
-        out, cin = {}, geom["input_shape"][2]
-        for i, ((name, _, relu), f, k) in enumerate(
-                zip(specs, filters, kernels)):
-            gain = 2.0 if relu else 1.0             # He-normal / 1/fan_in
-            w = jax.random.normal(ks[2 * i], (k, k, cin, f), jnp.float32)
-            b = jax.random.normal(ks[2 * i + 1], (f,), jnp.float32)
-            out[name] = {"w": w * (gain / (k * k * cin)) ** 0.5,
-                         "b": b * 0.01}
-            cin = f
-        out["caps"] = {"W": jax.random.normal(ks[-1], W_shape,
-                                              jnp.float32) * 0.1}
-        return out
-
-    return jax.jit(init)(key)
-
-
 @dataclasses.dataclass
 class Cell:
     config: dict
     mix: dict
+    model: object         # the configuration's models/<name>.py
     model_id: str
     registry: object
     engine: object
@@ -81,27 +52,19 @@ class Cell:
 def build_cell(config: dict, mix: dict, seed: int, *, annotate=None) -> Cell:
     import jax
     import jax.numpy as jnp
-    from repro.nn.config import CapsNetConfig
-    from repro.nn.pipeline import CapsPipeline
     from repro.serving import CapsServeEngine, ModelRegistry
 
     annotate = annotate or no_span
+    model = bench.model(config)
     g = config["geometry"]
     rngs = tr.streams(seed)
     with annotate("bench.make_inputs"):
-        params = make_params(g, rngs["weights"])
-        calib = images.make_images(config["images"], config["calib_n"],
-                                   rngs["calib"])
-        pool = images.make_images(config["images"], mix["pool"],
-                                  rngs["pool"])
-    cfg = CapsNetConfig(
-        config["name"], tuple(g["input_shape"]), tuple(g["conv_filters"]),
-        tuple(g["conv_kernels"]), tuple(g["conv_strides"]),
-        pcap_caps=g["pcap_caps"], pcap_dim=g["pcap_dim"],
-        pcap_kernel=g["pcap_kernel"], pcap_stride=g["pcap_stride"],
-        num_classes=g["num_classes"], caps_dim=g["caps_dim"],
-        routings=g["routings"])
-    pipe = CapsPipeline.from_config(cfg, per_channel=config["per_channel"])
+        params = model.make_params(g, rngs["weights"])
+        calib = images.make_images(config["images"], g["input_shape"],
+                                   config["calib_n"], rngs["calib"])
+        pool = images.make_images(config["images"], g["input_shape"],
+                                  mix["pool"], rngs["pool"])
+    pipe = model.pipeline(config)
     with annotate("bench.ptq"), \
             jax.default_matmul_precision(config["calibration_precision"]):
         qnet = pipe.quantize(params, jnp.asarray(calib),
@@ -120,7 +83,7 @@ def build_cell(config: dict, mix: dict, seed: int, *, annotate=None) -> Cell:
             for b in buckets:
                 engine.submit_many(pool[:b], model_id)
                 engine.drain()
-    return Cell(config=config, mix=mix, model_id=model_id,
+    return Cell(config=config, mix=mix, model=model, model_id=model_id,
                 registry=registry, engine=engine,
                 params=jax.device_get(params), calib=calib, pool=pool,
                 rngs=rngs, buckets=tuple(buckets))
@@ -201,9 +164,9 @@ def drive(cell: Cell, seconds: float, *, annotate=None,
     # what is still queued was due inside the window: serve and check it
     while eng.queue_depth() and clock() - t0 < span_s + DRAIN_LIMIT_S:
         rec.completed(eng.step(), clock() - t0)
-    g = cell.config["geometry"]
     n = len(rec.due)
-    v_q = np.zeros((n, g["num_classes"], g["caps_dim"]), np.int8)
+    J, O = cell.model.out_shape(cell.config["geometry"])
+    v_q = np.zeros((n, J, O), np.int8)
     pred = np.full(n, -1, np.int64)
     for slot, (v, p) in rec.answers.items():
         v_q[slot], pred[slot] = v, p
@@ -293,8 +256,8 @@ def check(cell: Cell, win: Window) -> dict:
     failed."""
     served = ~np.isnan(win.done_s)
     uniq, row = np.unique(win.pool_idx[served], return_inverse=True)
-    v_ref, p_ref = reference.reference(cell.config["geometry"], cell.params,
-                                       cell.calib, cell.pool[uniq], 8)
+    v_ref, p_ref = cell.model.reference(cell.config["geometry"], cell.params,
+                                        cell.calib, cell.pool[uniq], 8)
     bad_v = (win.v_q[served].astype(np.int64) != v_ref[row])
     bad_p = win.pred[served] != p_ref[row]
     numbers = {"vq_mismatch": int(bad_v.sum()),

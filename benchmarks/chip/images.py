@@ -2,7 +2,9 @@
 analogues of the program's `repro.data.synthetic.make_image_dataset`,
 copied here so that the benchmark's inputs do not move when the program
 does.  Class templates rendered with a random affine pose and noise,
-all drawn from one NumPy generator.
+all drawn from one NumPy generator.  The kind decides the content and
+the channels; the configuration's `input_shape` decides the size, the
+templates and shifts scaled with it from the kind's own size.
 """
 from __future__ import annotations
 
@@ -69,31 +71,44 @@ def _shape_mask(kind: int, size: int = 24) -> np.ndarray:
     return (r <= 0.45 + 0.4 * np.cos(5 * a) ** 2).astype(np.float32)  # star
 
 
+# (H, W, C, classes): each kind's own size, its channels and classes
 KINDS = {"mnist": (28, 28, 1, 10), "edge_tiny": (16, 16, 1, 4),
          "smallnorb": (32, 32, 2, 5), "cifar10": (32, 32, 3, 10)}
 
 
-def make_images(kind: str, n: int, rng: np.random.Generator) -> np.ndarray:
-    """n float32 NHWC images in [0, 1] of the analogue `kind`."""
-    H, W, C, ncls = KINDS[kind]
+def make_images(kind: str, shape, n: int,
+                rng: np.random.Generator) -> np.ndarray:
+    """n float32 NHWC images in [0, 1] of the analogue `kind` at `shape`
+    = (H, W, C); C has to be the kind's."""
+    H0, W0, C0, ncls = KINDS[kind]
+    H, W, C = shape
+    if C != C0:
+        raise ValueError(f"images {kind!r} have {C0} channel(s), not {C}")
+    z = min(H / H0, W / W0)
+
+    def px(v):
+        return max(1, round(v * z))
+
     imgs = np.zeros((n, H, W, C), np.float32)
     labels = rng.integers(0, ncls, n)
     for i in range(n):
         y = int(labels[i])
         if kind == "mnist":
-            imgs[i, :, :, 0] = _affine_place((H, W), _bitmap(DIGITS[y], 3),
-                                             rng)
+            imgs[i, :, :, 0] = _affine_place(
+                (H, W), _bitmap(DIGITS[y], px(3)), rng, max_shift=px(3))
         elif kind == "edge_tiny":
-            imgs[i, :, :, 0] = _affine_place((H, W), _bitmap(DIGITS[y], 2),
-                                             rng, max_shift=1)
+            imgs[i, :, :, 0] = _affine_place(
+                (H, W), _bitmap(DIGITS[y], px(2)), rng, max_shift=px(1))
         elif kind == "smallnorb":
-            base = _affine_place((H, W), _shape_mask(y), rng, rot=1.2)
+            base = _affine_place((H, W), _shape_mask(y, px(24)), rng,
+                                 rot=1.2, max_shift=px(3))
             light = rng.uniform(0.5, 1.0)
             shift = rng.integers(1, 3)
             imgs[i, :, :, 0] = base * light
             imgs[i, :, :, 1] = np.roll(base, shift, axis=1) * light
         else:
-            base = _affine_place((H, W), _shape_mask(y % 5), rng, rot=1.2)
+            base = _affine_place((H, W), _shape_mask(y % 5, px(24)), rng,
+                                 rot=1.2, max_shift=px(3))
             col = rng.uniform(0.6, 1.0, 3)
             col[y // 5] *= 0.3                    # class-dependent colour
             for ch in range(3):

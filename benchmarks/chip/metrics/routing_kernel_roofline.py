@@ -10,7 +10,8 @@ def read(ctx):
     t = (r or {}).get("class_s", {}).get("routing_kernel")
     if not t:
         return None
-    ops, nbytes = work.work(ctx.geom, {"routing"}, w.span_rows, w.span_waves)
+    ops, nbytes = work.work(ctx.model.layers(ctx.geom), {"routing"},
+                            w.span_rows, w.span_waves)
     least, _ = peaks.least_time_s(ops, nbytes, ctx.peaks["int8_ops"],
                                   ctx.peaks["hbm_bytes_per_s"])
     return 100.0 * least / t

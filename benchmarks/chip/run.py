@@ -46,6 +46,7 @@ class Context:
     """What a metric reader may read."""
     geom: dict
     config: dict
+    model: object                    # the configuration's models/<name>.py
     mix: dict
     seconds: float
     setup_s: float
@@ -110,23 +111,23 @@ def run_cell(spec: dict, seed: int, seconds: float, traced: bool,
                       for d in devices)
     reduction = None
     if traced:
-        classes = {}
+        classes, kernels = {}, {}
         for b in cell.buckets:
-            classes.update(trace.classify_hlo(
-                cell.registry.executable(cell.model_id, b)
-                .compiled.as_text()))
+            hlo = cell.registry.executable(cell.model_id, b) \
+                .compiled.as_text()
+            classes.update(trace.classify_hlo(hlo))
+            kernels.update(trace.kernel_names(hlo))
         events = trace.load_events(str(TRACE_DIR))
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
         marks = [(s, s + d) for n, s, d in events["host"]
                  if n == trace.WINDOW_SPAN]
-        reduction = trace.reduce(events, marks[0], classes)
+        reduction = trace.reduce(events, marks[0], classes, kernels)
     # the program's state goes before the reference runs
     cell.engine = cell.registry = None
     chk = harness.check(cell, win)
-    ctx = Context(geom=config["geometry"], config=config, mix=mix,
-                  seconds=seconds, setup_s=setup_s, window=win,
-                  peaks=chip_peaks,
-                  reduction=reduction, spans=spans)
+    ctx = Context(geom=config["geometry"], config=config, model=cell.model,
+                  mix=mix, seconds=seconds, setup_s=setup_s, window=win,
+                  peaks=chip_peaks, reduction=reduction, spans=spans)
     metrics = {}
     for m in spec["per_layer" if traced else "end_to_end"]:
         v = bench.reader(m["name"])(ctx)
@@ -172,7 +173,8 @@ def run_cell(spec: dict, seed: int, seconds: float, traced: bool,
     if traced:
         log.append(f"[trace] busy {reduction['busy_s']} s of "
                    f"{reduction['window_s']} s on {reduction['devices']} "
-                   f"device(s); device time by class {reduction['class_s']}")
+                   f"device(s); device time by class {reduction['class_s']}, "
+                   f"by kernel {reduction['kernel_s']}")
     return result, log
 
 

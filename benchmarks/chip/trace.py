@@ -9,10 +9,12 @@ after that works on tuples, so the arithmetic is tested without a chip.
     busy_ns        length of the union of intervals
     classify_hlo   HLO instruction name -> "conv" | "routing_kernel" |
                    "other", read off a compiled program's HLO text
+    kernel_names   HLO instruction name -> kernel name, for every Pallas
+                   (Mosaic) kernel of a compiled program
     reduce         busy and idle time of the traced window, device time
-                   per class, the operations that took most time, and
-                   the longest idle gaps named by the host span that
-                   covers them
+                   per class and per Pallas kernel, the operations that
+                   took most time, and the longest idle gaps named by
+                   the host span that covers them
 """
 from __future__ import annotations
 
@@ -143,9 +145,25 @@ def classify_hlo(hlo_text: str) -> dict:
     return classes
 
 
-def reduce(events: dict, window: tuple, classes: dict,
+_SUFFIX = re.compile(r"\.\d+$")
+
+
+def kernel_names(hlo_text: str) -> dict:
+    """Instruction name -> kernel name for every `tpu_custom_call` of the
+    compiled module.  The custom call is named after its kernel, with
+    XLA's `.N` suffix: `routing_q7_pallas.1` -> `routing_q7_pallas`."""
+    out = {}
+    for line in hlo_text.splitlines():
+        m = _INSTR.match(line)
+        if m and m.group(2) == "custom-call" and "tpu_custom_call" in line:
+            out[m.group(1)] = _SUFFIX.sub("", m.group(1))
+    return out
+
+
+def reduce(events: dict, window: tuple, classes: dict, kernels: dict,
            top: int = 10) -> dict:
-    """Reduce the traced events inside `window` = (start_ns, end_ns).
+    """Reduce the traced events inside `window` = (start_ns, end_ns);
+    `classes` from `classify_hlo`, `kernels` from `kernel_names`.
 
     Busy time is the union of the device's operation intervals, averaged
     over the devices that ran any.  An idle gap is named by the host span
@@ -157,6 +175,7 @@ def reduce(events: dict, window: tuple, classes: dict,
     window_ns = float(end - start)
     busy = class_ns = None
     op_ns: dict = collections.Counter()
+    kernel_ns: dict = collections.Counter()
     gaps: list = []
     if per_device:
         busies = []
@@ -165,7 +184,10 @@ def reduce(events: dict, window: tuple, classes: dict,
             busies.append(busy_ns((s, s + d) for _, s, d in evs))
             for name, s, d in evs:
                 op_ns[name] += d
-                class_ns[classes.get(instr_name(name), "other")] += d
+                instr = instr_name(name)
+                class_ns[classes.get(instr, "other")] += d
+                if instr in kernels:
+                    kernel_ns[kernels[instr]] += d
             merged = merge((s, s + d) for _, s, d in evs)
             edges = [start] + [x for iv in merged for x in iv] + [end]
             gaps += [(edges[i], edges[i + 1])
@@ -180,6 +202,8 @@ def reduce(events: dict, window: tuple, classes: dict,
         "window_s": window_ns / 1e9,
         "busy_s": None if busy is None else busy / 1e9,
         "class_s": {k: v / 1e9 for k, v in (class_ns or {}).items()},
+        "kernel_s": {k: v / len(per_device) / 1e9
+                     for k, v in kernel_ns.items()},
         "device_ops": [[n, t / 1e9] for n, t in op_ns.most_common(top)],
         "idle_gaps": named_gaps,
         "devices": len(per_device),

@@ -12,13 +12,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmarks.chip import control, harness, images, peaks, reference, run
-from benchmarks.chip import traffic
+from benchmarks.chip import (bench, control, harness, images, peaks,
+                             reference, run, traffic)
 
 # 16x16 gray -> conv8 k5 s2 -> 6x6 -> primary caps 4x4 k3 s2 -> 2x2x4 = 16
 # capsules -> 4 classes of 4, 2 routings
 TINY = {
     "name": "capsnet_tiny",
+    "model": "capsnet",
     "geometry": {"input_shape": [16, 16, 1], "conv_filters": [8],
                  "conv_kernels": [5], "conv_strides": [2], "pcap_caps": 4,
                  "pcap_dim": 4, "pcap_kernel": 3, "pcap_stride": 2,
@@ -30,6 +31,7 @@ TINY = {
 CLOSED = {"arrival": "closed", "depth_waves": 2, "pool": 24,
           "warm": "max_bucket"}
 OPEN = {"arrival": "open", "rate_per_s": 400, "pool": 24, "warm": "all"}
+CAPSNET = bench.model(TINY)
 
 
 def _spec(mix):
@@ -59,9 +61,9 @@ def test_reference_is_the_program_bit_for_bit(geometry):
                    "pcap_stride": 2, "num_classes": 10, "caps_dim": 6,
                    "routings": 3}, "mnist"
     rngs = traffic.streams(11)
-    params = harness.make_params(g, rngs["weights"])
-    calib = images.make_images(kind, 16, rngs["calib"])
-    x = images.make_images(kind, 6, rngs["pool"])
+    params = CAPSNET.make_params(g, rngs["weights"])
+    calib = images.make_images(kind, g["input_shape"], 16, rngs["calib"])
+    x = images.make_images(kind, g["input_shape"], 6, rngs["pool"])
     cfg = CapsNetConfig("t", tuple(g["input_shape"]),
                         tuple(g["conv_filters"]), tuple(g["conv_kernels"]),
                         tuple(g["conv_strides"]), pcap_caps=g["pcap_caps"],
@@ -71,8 +73,8 @@ def test_reference_is_the_program_bit_for_bit(geometry):
                         routings=g["routings"])
     qnet = CapsPipeline.from_config(cfg).quantize(params, jnp.asarray(calib))
     v = np.asarray(qnet.forward(qnet.quantize_input(jnp.asarray(x))))
-    v_ref, pred_ref = reference.reference(g, jax.device_get(params), calib,
-                                          x, 8)
+    v_ref, pred_ref = CAPSNET.reference(g, jax.device_get(params), calib,
+                                        x, 8)
     np.testing.assert_array_equal(v, v_ref)
     lengths = np.asarray(qnet.class_lengths(jnp.asarray(v)))
     np.testing.assert_array_equal(lengths.argmax(-1), pred_ref)

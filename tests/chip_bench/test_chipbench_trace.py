@@ -37,11 +37,13 @@ def _events():
 
 def test_reduce_idle_and_classes():
     r = trace.reduce(_events(), (0, 100),
-                     {"conv.1": "conv", "routing.1": "routing_kernel"})
+                     {"conv.1": "conv", "routing.1": "routing_kernel"},
+                     {"routing.1": "routing"})
     assert r["window_s"] == pytest.approx(100e-9)
     assert r["busy_s"] == pytest.approx(35e-9)          # 10..30, 50..60, 90..95
     assert r["class_s"] == pytest.approx(
         {"conv": 20e-9, "routing_kernel": 15e-9, "other": 5e-9})
+    assert r["kernel_s"] == pytest.approx({"routing": 15e-9})
     assert r["device_ops"][0] == ["conv.1", pytest.approx(20e-9)]
     # gaps [0,10) [30,50) [60,90) [95,100), longest first, each named by
     # the innermost host span that covers most of it
@@ -65,7 +67,8 @@ def test_tpu_events_named_by_instruction_text():
         ("%copy.10 = s32[1024,64,10,6]{0,3,2,1:T(8,128)S(1)} copy(%fusion)",
          90, 5)]
     r = trace.reduce(ev, (0, 100), {"clamp_convert_fusion.2": "conv",
-                                    "routing_q7_pallas.1": "routing_kernel"})
+                                    "routing_q7_pallas.1": "routing_kernel"},
+                     {"routing_q7_pallas.1": "routing_q7_pallas"})
     assert r["class_s"] == pytest.approx(
         {"conv": 10e-9, "routing_kernel": 15e-9, "other": 5e-9})
     assert trace.instr_name("routing_q7_pallas.1") == "routing_q7_pallas.1"
@@ -80,8 +83,9 @@ def test_gap_named_by_the_span_covering_most_of_it():
 
 
 def test_no_device_ops_reads_nothing():
-    r = trace.reduce({"device": {}, "host": []}, (0, 100), {})
+    r = trace.reduce({"device": {}, "host": []}, (0, 100), {}, {})
     assert r["busy_s"] is None and r["devices"] == 0
+    assert r["kernel_s"] == {}
 
 
 HLO = """\
@@ -117,6 +121,13 @@ def test_classify_hlo():
     assert "fusion" not in c      # u_hat's dot, lowered to a convolution
 
 
+def test_kernel_names():
+    """Every Mosaic kernel by its own name, and nothing else."""
+    assert trace.kernel_names(HLO) == {
+        "squash_q7_pallas.1": "squash_q7_pallas",
+        "routing_q7_pallas.1": "routing_q7_pallas"}
+
+
 @pytest.mark.parametrize("name, convs", [("mnist_L", 4), ("cifar10_S", 10)])
 def test_classify_a_wave_compiled_for_v5e(name, convs):
     """The bucket-64 `@pallas` wave of each configuration as the TPU
@@ -136,7 +147,9 @@ def test_reduce_a_trace_recorded_on_v5e(name, busy, conv, routing):
     device ops named by instruction text, the `bench.*` host spans, and
     the classes `classify_hlo` read off that run's compiled wave."""
     rec = json.loads((FIXTURES / f"{name}.backlog.trace.json").read_text())
-    r = trace.reduce(rec, tuple(rec["window"]), rec["classes"])
+    hlo = (FIXTURES / f"{name}.wave64.hlo.txt").read_text()
+    r = trace.reduce(rec, tuple(rec["window"]), rec["classes"],
+                     trace.kernel_names(hlo))
     assert r["devices"] == 1
     assert r["busy_s"] == pytest.approx(busy)
     assert r["busy_s"] <= r["window_s"]
@@ -144,5 +157,21 @@ def test_reduce_a_trace_recorded_on_v5e(name, busy, conv, routing):
     assert r["class_s"]["routing_kernel"] == pytest.approx(routing)
     assert all(g[0].startswith("bench.") for g in r["idle_gaps"])
     # the chip's compile classifies as the described v5e's did
-    c = trace.classify_hlo((FIXTURES / f"{name}.wave64.hlo.txt").read_text())
+    c = trace.classify_hlo(hlo)
     assert rec["classes"].items() <= c.items()
+
+
+@pytest.mark.parametrize("name", ["mnist_L", "cifar10_S"])
+def test_kernel_time_on_a_trace_recorded_on_v5e(name):
+    """Device time per Pallas kernel: the routing kernel's is its class's,
+    and the squash kernel, which has no class of its own, has one."""
+    rec = json.loads((FIXTURES / f"{name}.backlog.trace.json").read_text())
+    kernels = trace.kernel_names(
+        (FIXTURES / f"{name}.wave64.hlo.txt").read_text())
+    assert sorted(kernels.values()) == ["routing_q7_pallas",
+                                        "squash_q7_pallas"]
+    r = trace.reduce(rec, tuple(rec["window"]), rec["classes"], kernels)
+    assert set(r["kernel_s"]) == {"routing_q7_pallas", "squash_q7_pallas"}
+    assert r["kernel_s"]["routing_q7_pallas"] == \
+        r["class_s"]["routing_kernel"]
+    assert 0 < r["kernel_s"]["squash_q7_pallas"] <= r["class_s"]["other"]

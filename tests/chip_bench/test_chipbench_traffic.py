@@ -9,8 +9,10 @@ from benchmarks.chip import images, traffic
 
 def test_same_seed_same_images_and_arrivals():
     a, b = traffic.streams(2**31 + 17), traffic.streams(2**31 + 17)
-    np.testing.assert_array_equal(images.make_images("mnist", 8, a["pool"]),
-                                  images.make_images("mnist", 8, b["pool"]))
+    mnist = images.KINDS["mnist"][:3]
+    np.testing.assert_array_equal(
+        images.make_images("mnist", mnist, 8, a["pool"]),
+        images.make_images("mnist", mnist, 8, b["pool"]))
     np.testing.assert_array_equal(traffic.due_times(500, 2, a["arrivals"]),
                                   traffic.due_times(500, 2, b["arrivals"]))
     c = traffic.streams(2**31 + 18)
@@ -20,10 +22,21 @@ def test_same_seed_same_images_and_arrivals():
 
 @pytest.mark.parametrize("kind", sorted(images.KINDS))
 def test_images_have_the_geometry_and_range(kind):
-    x = images.make_images(kind, 3, np.random.default_rng(0))
     h, w, c, _ = images.KINDS[kind]
+    x = images.make_images(kind, (h, w, c), 3, np.random.default_rng(0))
     assert x.shape == (3, h, w, c) and x.dtype == np.float32
     assert 0.0 <= x.min() and x.max() <= 1.0
+
+
+@pytest.mark.parametrize("kind", sorted(images.KINDS))
+def test_the_configuration_sets_the_size_and_the_kind_the_channels(kind):
+    h, w, c, _ = images.KINDS[kind]
+    x = images.make_images(kind, (2 * h, 3 * w, c), 3,
+                           np.random.default_rng(0))
+    assert x.shape == (3, 2 * h, 3 * w, c) and x.dtype == np.float32
+    assert 0.0 <= x.min() and 0.5 < x.max() <= 1.0   # a template is drawn
+    with pytest.raises(ValueError, match="channel"):
+        images.make_images(kind, (h, w, c + 1), 1, np.random.default_rng(0))
 
 
 @pytest.mark.parametrize("seed", [1, 2, 2**33 + 5])

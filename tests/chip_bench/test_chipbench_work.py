@@ -9,6 +9,7 @@ import pytest
 from benchmarks.chip import bench, peaks, work
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
+CAPSNET = bench.model({"model": "capsnet"})
 
 
 def _geom(name):
@@ -30,23 +31,24 @@ def _geom(name):
 ])
 def test_macs_per_image_match_hand_counts(config, macs, per_layer):
     g = _geom(config)
-    assert work.macs_per_image(g) == macs
-    assert {l["name"]: l["macs"] for l in work.layers(g)} == per_layer
+    assert work.macs_per_image(CAPSNET.layers(g)) == macs
+    assert {l["name"]: l["macs"] for l in CAPSNET.layers(g)} == per_layer
 
 
 def test_conv_share_of_mnist_work():
     g = _geom("capsnet_mnist_L")
-    assert work.macs_per_image(g, {"conv"}) == 3_590_720
-    assert work.input_caps(g) == 1024
+    assert work.macs_per_image(CAPSNET.layers(g), {"conv"}) == 3_590_720
+    assert CAPSNET.input_caps(g) == 1024
 
 
 def test_work_counts_padding_rows_and_weights_once_per_wave():
     g = _geom("capsnet_mnist_L")
-    ops, nbytes = work.work(g, {"routing"}, rows=128, waves=2)
+    ops, nbytes = work.work(CAPSNET.layers(g), {"routing"}, rows=128,
+                            waves=2)
     assert ops == 2 * 307_200 * 128
     # u_hat int8 read (10 x 1024 x 6) and v written (10 x 6) per row
     assert nbytes == (61_440 + 60) * 128
-    ops, nbytes = work.work(g, {"conv"}, rows=64, waves=1)
+    ops, nbytes = work.work(CAPSNET.layers(g), {"conv"}, rows=64, waves=1)
     conv_w = 7 * 7 * 1 * 16 + 16 + 7 * 7 * 16 * 64 + 64
     assert nbytes == (784 + 7744 + 7744 + 4096) * 64 + conv_w
 
@@ -111,4 +113,4 @@ def test_configs_hold_the_published_geometry(config, program):
             g["routings"]) == (c.pcap_caps, c.pcap_dim, c.pcap_kernel,
                                c.pcap_stride, c.num_classes, c.caps_dim,
                                c.routings)
-    assert work.input_caps(g) == c.num_input_caps
+    assert CAPSNET.input_caps(g) == c.num_input_caps
