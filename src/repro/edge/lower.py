@@ -38,6 +38,21 @@ def _np(x):
     return np.asarray(jax.device_get(x))
 
 
+def _check_lowerable(layer) -> None:
+    """The schedule is a chain of VALID convs, flat primary capsules and
+    class routing; anything else (SAME padding, a capsule grid, a layer
+    that names its inputs) raises before a wrong shape is emitted."""
+    conv = layer.conv if isinstance(layer, PrimaryCaps) else layer
+    if (isinstance(layer, (PrimaryCaps, QuantConv2D, CapsuleRouting))
+            and not layer.inputs and not getattr(layer, "grid", False)
+            and getattr(conv, "padding", "VALID") == "VALID"):
+        return
+    raise TypeError(
+        f"no lowering for layer {layer.name!r} ({type(layer).__name__}); "
+        "teach repro.edge.lower about new CapsLayer kinds before "
+        "exporting them")
+
+
 def lower(qnet: QuantCapsNet, name: str | None = None) -> EdgeProgram:
     """Compile any quantized CapsNet (per-tensor or per-channel plans,
     either rounding mode) into the flat MCU schedule."""
@@ -55,6 +70,7 @@ def lower(qnet: QuantCapsNet, name: str | None = None) -> EdgeProgram:
 
     cur = 0
     for layer in qnet.pipeline.layers:
+        _check_lowerable(layer)
         plan = qnet.plan[layer.name]
         qw = {k: _np(v) for k, v in qnet.qweights[layer.name].items()}
         if isinstance(layer, PrimaryCaps):
@@ -102,11 +118,6 @@ def lower(qnet: QuantCapsNet, name: str | None = None) -> EdgeProgram:
                              plan.out_frac)
             ops.append(EdgeOp("CAPS_ROUTING_Q7", layer.name, (cur,), out,
                               attrs, qw))
-        else:
-            raise TypeError(
-                f"no lowering for layer {layer.name!r} "
-                f"({type(layer).__name__}); teach repro.edge.lower about "
-                "new CapsLayer kinds before exporting them")
         cur = out
 
     program = EdgeProgram(name=name, rounding=qnet.rounding,

@@ -93,11 +93,17 @@ def squash_float(s, interpret: bool | None = None):
 
 def routing_q7(u_hat, num_iters: int, caps_out_shifts, caps_out_fracs,
                agree_shifts, logit_frac: int, rounding: str = "floor",
-               interpret: bool | None = None):
-    """Fused dynamic routing: u_hat [B,J,I,O] int8 -> v [B,J,O] int8."""
+               interpret: bool | None = None, bias=None, local: bool = False):
+    """Fused dynamic routing: u_hat [B,J,I,O] int8 -> v [B,J,O] int8.
+
+    `bias` (int32 [J, O], in the accumulator's format) is added to s
+    before each iteration's shift.  `local=True` runs the grouped entry
+    (`routing3d_q7_pallas`: B is then the number of local groups)."""
     interpret = default_interpret() if interpret is None else interpret
-    return _routing.routing_q7_pallas(
-        u_hat, num_iters=num_iters,
+    entry = _routing.routing3d_q7_pallas if local \
+        else _routing.routing_q7_pallas
+    return entry(
+        u_hat, bias, num_iters=num_iters,
         caps_out_shifts=tuple(caps_out_shifts),
         caps_out_fracs=tuple(caps_out_fracs),
         agree_shifts=tuple(agree_shifts), logit_frac=logit_frac,

@@ -65,14 +65,17 @@ def _rshift_sat8(acc, shift: int, rounding: str):
     return jnp.clip(acc, INT8_MIN, INT8_MAX)
 
 
-def _routing_kernel(u_ref, v_ref, *, num_iters, caps_out_shifts,
+def _routing_kernel(u_ref, *refs, num_iters, caps_out_shifts,
                     caps_out_fracs, agree_shifts, logit_frac, rounding):
     # I sits on the 128-wide lane axis and O on the sublanes; every
     # intermediate keeps rank 3 (keepdims) so no reduction or broadcast
     # has to move data between the two axes.  The two contractions are a
     # VPU multiply plus a reduction: at O = 4-6 they are far too narrow
     # for the MXU, and Mosaic cannot lower a batched dot in which one
-    # operand has no free dimension.
+    # operand has no free dimension.  `refs` is (v_ref,) or, with a bias,
+    # (bias_ref, v_ref): the bias [J, O, 1] int32 is already in the
+    # accumulator's format and is added to s before its shift.
+    *bias_ref, v_ref = refs
     u = u_ref[0].astype(jnp.int32)              # [J, O, I] resident in VMEM
     J, O, I = u.shape
     b = jnp.zeros((J, 1, I), jnp.int32)
@@ -80,6 +83,8 @@ def _routing_kernel(u_ref, v_ref, *, num_iters, caps_out_shifts,
     for r in range(num_iters):
         c = _softmax_q7_cols(b, logit_frac)                      # [J, 1, I]
         s = jnp.sum(c * u, axis=2, keepdims=True)                # [J, O, 1]
+        if bias_ref:
+            s = s + bias_ref[0][...]
         s_q = _rshift_sat8(s, caps_out_shifts[r], rounding)
         v = _squash_caps(s_q, in_frac=caps_out_fracs[r])         # [J, O, 1]
         if r < num_iters - 1:
@@ -89,27 +94,57 @@ def _routing_kernel(u_ref, v_ref, *, num_iters, caps_out_shifts,
     v_ref[0] = v
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "num_iters", "caps_out_shifts", "caps_out_fracs", "agree_shifts",
-    "logit_frac", "rounding", "interpret"))
-def routing_q7_pallas(u_hat, *, num_iters: int, caps_out_shifts: tuple,
-                      caps_out_fracs: tuple, agree_shifts: tuple,
-                      logit_frac: int, rounding: str, interpret: bool):
-    """u_hat int8 [B, J, I, O] -> v int8 [B, J, O], all r iterations fused.
-
-    The kernel reads u_hat as [B, J, O, I], with I on the lanes; the
-    transpose is an XLA op outside the kernel."""
+def _route(u_hat, bias, *, interpret, **plan):
+    """The fused loop over every row of u_hat int8 [B, J, I, O], one row
+    a grid step; the kernel reads it as [B, J, O, I], with I on the
+    lanes (the transpose is an XLA op outside the kernel)."""
     B, J, I, O = u_hat.shape
+    in_specs = [pl.BlockSpec((1, J, O, I), lambda b: (b, 0, 0, 0))]
+    args = [jnp.swapaxes(u_hat, 2, 3)]
+    if bias is not None:
+        in_specs.append(pl.BlockSpec((J, O, 1), lambda b: (0, 0, 0)))
+        args.append(jnp.asarray(bias, jnp.int32).reshape(J, O, 1))
     v = pl.pallas_call(
-        functools.partial(
-            _routing_kernel, num_iters=num_iters,
-            caps_out_shifts=caps_out_shifts, caps_out_fracs=caps_out_fracs,
-            agree_shifts=agree_shifts, logit_frac=logit_frac,
-            rounding=rounding),
+        functools.partial(_routing_kernel, **plan),
         grid=(B,),
-        in_specs=[pl.BlockSpec((1, J, O, I), lambda b: (b, 0, 0, 0))],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((1, J, O, 1), lambda b: (b, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, J, O, 1), jnp.int32),
         interpret=interpret,
-    )(jnp.swapaxes(u_hat, 2, 3))
+    )(*args)
     return v[..., 0].astype(jnp.int8)
+
+
+_STATIC = ("num_iters", "caps_out_shifts", "caps_out_fracs", "agree_shifts",
+           "logit_frac", "rounding", "interpret")
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def routing_q7_pallas(u_hat, bias=None, *, num_iters: int,
+                      caps_out_shifts: tuple, caps_out_fracs: tuple,
+                      agree_shifts: tuple, logit_frac: int, rounding: str,
+                      interpret: bool):
+    """u_hat int8 [B, J, I, O] -> v int8 [B, J, O], all r iterations
+    fused, one sample a grid step.  `bias` (int32 [J, O], in the
+    accumulator's format) is added to every s before its shift."""
+    return _route(u_hat, bias, num_iters=num_iters,
+                  caps_out_shifts=caps_out_shifts,
+                  caps_out_fracs=caps_out_fracs, agree_shifts=agree_shifts,
+                  logit_frac=logit_frac, rounding=rounding,
+                  interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def routing3d_q7_pallas(u_hat, bias=None, *, num_iters: int,
+                        caps_out_shifts: tuple, caps_out_fracs: tuple,
+                        agree_shifts: tuple, logit_frac: int, rounding: str,
+                        interpret: bool):
+    """Local routing: u_hat int8 [G, J, I, O] -> v int8 [G, J, O] for G
+    independent groups (one per batch row and output position), one
+    group a grid step.  The same kernel body as `routing_q7_pallas`,
+    under its own name so that a trace keeps the two apart."""
+    return _route(u_hat, bias, num_iters=num_iters,
+                  caps_out_shifts=caps_out_shifts,
+                  caps_out_fracs=caps_out_fracs, agree_shifts=agree_shifts,
+                  logit_frac=logit_frac, rounding=rounding,
+                  interpret=interpret)

@@ -34,17 +34,20 @@ class JnpBackend:
 
     name = "jnp"
 
-    def conv2d_q7(self, x, w, b, out_shift, bias_shift, *, stride, rounding):
+    def conv2d_q7(self, x, w, b, out_shift, bias_shift, *, stride, rounding,
+                  padding="VALID"):
         return q.conv2d_q7(x, w, b, out_shift, bias_shift,
-                           stride=stride, rounding=rounding)
+                           stride=stride, padding=padding,
+                           rounding=rounding)
 
     def conv2d_q7_per_channel(self, x, w, b, out_shifts, bias_shifts, *,
-                              stride, rounding):
+                              stride, rounding, padding="VALID"):
         """Per-output-channel requantization (ConvPlan.per_channel).  The
         conv itself is the same XLA int8 conv on every backend; only the
         shift step becomes a table lookup, so Pallas inherits this."""
         return q.conv2d_q7_per_channel(x, w, b, out_shifts, bias_shifts,
-                                       stride=stride, rounding=rounding)
+                                       stride=stride, padding=padding,
+                                       rounding=rounding)
 
     def relu_q7(self, x):
         return q.relu_q7(x)
@@ -70,8 +73,10 @@ class JnpBackend:
             return q.rshift_sat8_vec(acc, shifts, rounding)
         return q.rshift_sat8(acc, shift, rounding)
 
-    def routing_q7(self, u_hat, plan, *, rounding):
-        """Alg. 5's r-iteration loop over an already-computed u_hat."""
+    def routing_q7(self, u_hat, plan, *, rounding, bias=None):
+        """Alg. 5's r-iteration loop over an already-computed u_hat.
+        `bias` (int32 [J, O], in the accumulator's format) is added to
+        every s before its requantizing shift."""
         b = jnp.zeros(u_hat.shape[:3], jnp.int8)
         v = None
         for r in range(plan.routings):
@@ -79,6 +84,8 @@ class JnpBackend:
                                 impl=plan.softmax_impl).swapaxes(1, 2)
             acc = jnp.einsum("bji,bjio->bjo", c.astype(jnp.int32),
                              u_hat.astype(jnp.int32))
+            if bias is not None:
+                acc = acc + bias
             s_q = q.rshift_sat8(acc, plan.caps_out_shifts[r], rounding)
             v = self.squash_q7(s_q, in_frac=plan.caps_out_fracs[r],
                                out_frac=plan.out_frac,
@@ -93,6 +100,11 @@ class JnpBackend:
                     acc, plan.agree_shifts[r] + plan.out_frac - 7, rounding)
                 b = q.add_q7(b, a)
         return v
+
+    def local_routing_q7(self, u_hat, plan, *, rounding, bias=None):
+        """Local routing: the same loop over u_hat [G, J, I, O], each of
+        the G rows a local group (a batch row at one output position)."""
+        return self.routing_q7(u_hat, plan, rounding=rounding, bias=bias)
 
 
 # the fallback target for PallasBackend: a plain oracle instance, so a
@@ -149,18 +161,17 @@ class PallasBackend(JnpBackend):
             lambda x: kops.squash_q7(x, in_frac=in_frac, out_frac=out_frac),
             s)
 
-    def routing_q7(self, u_hat, plan, *, rounding):
+    def _fused_routing(self, op, u_hat, plan, *, rounding, bias, local):
         # the fused kernel implements only the default variants and the
         # Q0.7 squash output; other plans take the oracle loop
-        if plan.softmax_impl != REGISTRY.default("softmax"):
-            self._fallback("routing.softmax", plan.softmax_impl)
-            return _JNP_ORACLE.routing_q7(u_hat, plan, rounding=rounding)
-        if plan.squash_impl != REGISTRY.default("squash"):
-            self._fallback("routing.squash", plan.squash_impl)
-            return _JNP_ORACLE.routing_q7(u_hat, plan, rounding=rounding)
-        if plan.out_frac != 7:
-            self._fallback("routing.out_frac", f"Q0.{plan.out_frac}")
-            return _JNP_ORACLE.routing_q7(u_hat, plan, rounding=rounding)
+        for part, have, default in (
+                ("softmax", plan.softmax_impl, REGISTRY.default("softmax")),
+                ("squash", plan.squash_impl, REGISTRY.default("squash")),
+                ("out_frac", f"Q0.{plan.out_frac}", "Q0.7")):
+            if have != default:
+                self._fallback(f"{op}.{part}", have)
+                return _JNP_ORACLE.routing_q7(u_hat, plan,
+                                              rounding=rounding, bias=bias)
         from repro.kernels import ops as kops
         return api.batch_local(
             lambda u: kops.routing_q7(
@@ -168,8 +179,19 @@ class PallasBackend(JnpBackend):
                 caps_out_shifts=plan.caps_out_shifts,
                 caps_out_fracs=plan.caps_out_fracs,
                 agree_shifts=plan.agree_shifts,
-                logit_frac=plan.logit_frac, rounding=rounding),
+                logit_frac=plan.logit_frac, rounding=rounding, bias=bias,
+                local=local),
             u_hat)
+
+    def routing_q7(self, u_hat, plan, *, rounding, bias=None):
+        return self._fused_routing("routing", u_hat, plan, rounding=rounding,
+                                   bias=bias, local=False)
+
+    def local_routing_q7(self, u_hat, plan, *, rounding, bias=None):
+        """The grouped entry (`routing3d_q7_pallas`), one group a grid
+        step; its fallbacks count under the op "routing3d"."""
+        return self._fused_routing("routing3d", u_hat, plan,
+                                   rounding=rounding, bias=bias, local=True)
 
 
 BACKENDS = {"jnp": JnpBackend(), "pallas": PallasBackend(metrics=METRICS)}

@@ -59,3 +59,39 @@ CIFAR10 = CapsNetConfig("capsnet_cifar10", (32, 32, 3), (32, 32, 64, 64),
                         (3, 3, 3, 3), (1, 1, 2, 2), pcap_kernel=3,
                         num_classes=10, caps_dim=5, lr=2.5e-4)
 CAPSNET_CONFIGS = {c.name: c for c in (MNIST, SMALLNORB, CIFAR10)}
+
+
+@dataclasses.dataclass(frozen=True)
+class DeepCapsConfig:
+    """DeepCaps (Rajasegaran et al., CVPR 2019, arXiv:1904.09546).
+
+    A 3x3 SAME conv stem (`stem_filters`, relu), then one capsule cell
+    per entry of `cells` (capsule types, atoms), each halving the grid,
+    and `num_classes` class capsules of `caps_dim` routed from the last
+    two cells' capsules (`CapsPipeline.from_deepcaps` builds it).  The
+    CIFAR-10 model at 64x64x3: cells 32x4 at 32x32, 32x8 at 16x16, 8x8
+    and 4x4 -> 512 + 2,048 = 2,560 capsules of 8D -> 10 x 32D."""
+    name: str
+    input_shape: tuple = (64, 64, 3)
+    stem_filters: int = 128
+    cells: tuple = ((32, 4), (32, 8), (32, 8), (32, 8))
+    num_classes: int = 10
+    caps_dim: int = 32
+    routings: int = 3
+
+    def cell_hw(self, n: int) -> tuple:
+        """The grid of cell `n` (1-based): each halves it, SAME padded."""
+        h, w = self.input_shape[0], self.input_shape[1]
+        for _ in range(n):
+            h, w = -(-h // 2), -(-w // 2)
+        return h, w
+
+    @property
+    def num_input_caps(self) -> int:
+        """Capsules of the last two cells, which the class layer reads."""
+        n = len(self.cells)
+        return sum(self.cell_hw(k)[0] * self.cell_hw(k)[1]
+                   * self.cells[k - 1][0] for k in (n, n - 1))
+
+
+DEEPCAPS_CIFAR10 = DeepCapsConfig("deepcaps_cifar10")

@@ -1,9 +1,17 @@
-"""CapsPipeline: one typed graph walk for all three execution faces.
+"""CapsPipeline: one typed graph walk for every execution face.
 
   forward    — float inference (optionally returning calibration taps)
   calibrate  — max|x| per tap over a reference dataset (Alg. 6 line 8)
+  plan       — per-layer Qm.n plans, each layer's `in_frac` the format
+               of the layer it reads
   quantize   — per-layer plans + int8 weights -> a QuantCapsNet
+  forward_fq — fake-quantized float forward (QAT)
   forward_q7 — int8 inference on a selectable op backend
+
+The layers are in execution order.  Each reads the output of the layer
+before it, or of the layers its `inputs` name ("input" is the image), so
+skips and concatenations (DeepCaps) walk the same way as the template's
+chain (`_walk`).
 
 The pipeline owns nothing numeric: every operation, tap, format and shift
 belongs to a layer.  Adding a layer kind (deeper stacks, approximate-op
@@ -17,17 +25,31 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-from repro.nn.config import CapsNetConfig
-from repro.nn.layers import CapsuleRouting, PrimaryCaps, QuantConv2D
+from repro.nn.config import CapsNetConfig, DeepCapsConfig
+from repro.nn.layers import (CapsAdd, CapsConcat, CapsuleRouting, ConvCaps3D,
+                             PrimaryCaps, QuantConv2D)
 from repro.nn.plans import PipelinePlan, TapStats, plan_scalars
 from repro.nn.variants import VariantSet
 from repro.obs import numerics as _health
 from repro.quant import qformat as qf
 
 
+def _walk(layers, h, apply):
+    """Run `apply(layer, inp)` over the layers in order and return the
+    last one's result.  `inp` is the result of the layer's named input,
+    a tuple of them for several, or the previous layer's result."""
+    outs, last = {"input": h}, "input"
+    for l in layers:
+        srcs = l.inputs or (last,)
+        outs[l.name] = apply(l, outs[srcs[0]] if len(srcs) == 1
+                             else tuple(outs[s] for s in srcs))
+        last = l.name
+    return outs[last]
+
+
 @dataclasses.dataclass(frozen=True)
 class CapsPipeline:
-    cfg: CapsNetConfig
+    cfg: CapsNetConfig | DeepCapsConfig
     layers: tuple
 
     # ------------------------------------------------------------------
@@ -74,6 +96,37 @@ class CapsPipeline:
             squash_impl=variants.squash, per_channel=per_channel_w))
         return cls(cfg=cfg, layers=tuple(layers))
 
+    @classmethod
+    def from_deepcaps(cls, cfg: DeepCapsConfig) -> "CapsPipeline":
+        """DeepCaps (arXiv:1904.09546): a SAME conv stem, capsule cells
+        `a = CC(h, 2); out = CC(CC(a, 1), 1) + skip(a)` with skip = CC(a,
+        1) in cells 1-3 and ConvCaps3D in the last, then class capsules
+        routed from the last two cells' capsules concatenated."""
+        def cc(name, inp, stride, in_ch, caps, dim):
+            return PrimaryCaps(name, 3, stride, in_ch, caps, dim,
+                               padding="SAME", grid=True, inputs=(inp,))
+
+        layers = [QuantConv2D("stem", 3, 1, cfg.input_shape[2],
+                              cfg.stem_filters, relu=True, padding="SAME")]
+        src, cin = "stem", cfg.stem_filters
+        for n, (caps, dim) in enumerate(cfg.cells, start=1):
+            c, width = f"cell{n}", caps * dim
+            skip = ConvCaps3D(f"{c}/skip3d", caps, dim, caps, dim,
+                              routings=cfg.routings, inputs=(f"{c}/a",)) \
+                if n == len(cfg.cells) \
+                else cc(f"{c}/skip", f"{c}/a", 1, width, caps, dim)
+            layers += [cc(f"{c}/a", src, 2, cin, caps, dim),
+                       cc(f"{c}/b", f"{c}/a", 1, width, caps, dim),
+                       cc(f"{c}/c", f"{c}/b", 1, width, caps, dim), skip,
+                       CapsAdd(c, (f"{c}/c", skip.name))]
+            src, cin = c, width
+        n = len(cfg.cells)
+        layers.append(CapsConcat("concat", (f"cell{n}", f"cell{n - 1}")))
+        layers.append(CapsuleRouting("caps", cfg.num_classes,
+                                     cfg.num_input_caps, cfg.caps_dim,
+                                     cfg.cells[-1][1], cfg.routings))
+        return cls(cfg=cfg, layers=tuple(layers))
+
     def layer(self, name: str):
         for l in self.layers:
             if l.name == name:
@@ -95,11 +148,13 @@ class CapsPipeline:
     def forward(self, params, x, *, with_taps: bool = False):
         """x [B,H,W,C] float in [0,1] -> class capsules [B, J, O]."""
         taps = {"input": x}
-        h = x
-        for l in self.layers:
-            h, t = l.fwd_f32(params[l.name], h)
+
+        def step(l, h):
+            h, t = l.fwd_f32(params.get(l.name, {}), h)
             for k, v in t.items():
                 taps[f"{l.name}.{k}"] = v
+            return h
+        h = _walk(self.layers, x, step)
         return (h, taps) if with_taps else h
 
     def tap_names(self) -> tuple:
@@ -135,15 +190,15 @@ class CapsPipeline:
     # planning + quantization face (Alg. 6 & 7)
     # ------------------------------------------------------------------
     def plan(self, params, stats: TapStats) -> PipelinePlan:
-        """Each layer derives its own plan; the activation format chains
-        through `out_frac` -> next layer's `in_frac`."""
+        """Each layer derives its own plan; a layer's `in_frac` is the
+        `out_frac` of the layer it reads (a tuple for several)."""
         input_frac = qf.frac_bits(stats["input"])
-        f_act = input_frac
         plans: dict = {}
-        for l in self.layers:
-            p = l.plan(params[l.name], stats, f_act)
-            plans[l.name] = p
-            f_act = p.out_frac
+
+        def step(l, f_in):
+            plans[l.name] = l.plan(params.get(l.name, {}), stats, f_in)
+            return plans[l.name].out_frac
+        _walk(self.layers, input_frac, step)
         return PipelinePlan(input_frac=input_frac, layers=plans)
 
     def quantize(self, params, calib_images, *, rounding: str = "floor",
@@ -154,7 +209,8 @@ class CapsPipeline:
         with obs.span("ptq.plan", config=self.cfg.name):
             plan = self.plan(params, stats)
         with obs.span("ptq.quantize_weights", config=self.cfg.name):
-            qweights = {l.name: l.quantize(params[l.name], plan[l.name])
+            qweights = {l.name: l.quantize(params.get(l.name, {}),
+                                           plan[l.name])
                         for l in self.layers}
         return QuantCapsNet(pipeline=self, plan=plan, qweights=qweights,
                             rounding=rounding, backend=backend)
@@ -168,19 +224,22 @@ class CapsPipeline:
         on the plan's Qm.n grids (straight-through gradients).  The plan
         comes from the SAME `plan()` machinery PTQ uses, so a QAT model
         quantizes/lowers/serves with zero new conversion code."""
-        if _health._PROBE is None:                 # hot path untouched
-            h = qf.fake_quant(x, plan.input_frac)
-            for l in self.layers:
-                h = l.fwd_fq(params[l.name], plan[l.name], h,
-                             rounding=rounding)
-            return h
+        index = {l.name: i for i, l in enumerate(self.layers)}
+
+        def step(l, h):
+            if _health._PROBE is None:             # hot path untouched
+                return l.fwd_fq(params.get(l.name, {}), plan[l.name], h,
+                                rounding=rounding)
+            with _health.scope(l.name, index=index[l.name],
+                               kind=type(l).__name__):
+                return l.fwd_fq(params.get(l.name, {}), plan[l.name], h,
+                                rounding=rounding)
+        if _health._PROBE is None:
+            return _walk(self.layers, qf.fake_quant(x, plan.input_frac),
+                         step)
         with _health.scope("input"):
             h = qf.fake_quant(x, plan.input_frac)
-        for i, l in enumerate(self.layers):
-            with _health.scope(l.name, index=i, kind=type(l).__name__):
-                h = l.fwd_fq(params[l.name], plan[l.name], h,
-                             rounding=rounding)
-        return h
+        return _walk(self.layers, h, step)
 
     # ------------------------------------------------------------------
     # int8 face
@@ -188,23 +247,22 @@ class CapsPipeline:
     def forward_q7(self, qweights, plan: PipelinePlan, x_q, *,
                    backend: str = "jnp", rounding: str = "floor"):
         """x_q int8 image in the plan's input format -> v int8 [B,J,O]."""
-        if _health._PROBE is None:                 # hot path untouched
-            h = x_q
-            for l in self.layers:
-                with jax.named_scope(l.name):      # trace metadata only
+        index = {l.name: i for i, l in enumerate(self.layers)}
+
+        def step(l, h):
+            with jax.named_scope(l.name):          # trace metadata only
+                if _health._PROBE is None:         # hot path untouched
+                    return l.fwd_q7(qweights[l.name], plan[l.name], h,
+                                    backend=backend, rounding=rounding)
+                with _health.scope(l.name, index=index[l.name],
+                                   kind=type(l).__name__):
                     h = l.fwd_q7(qweights[l.name], plan[l.name], h,
                                  backend=backend, rounding=rounding)
-            return h
-        h = x_q
-        for i, l in enumerate(self.layers):
-            with jax.named_scope(l.name), \
-                    _health.scope(l.name, index=i, kind=type(l).__name__):
-                h = l.fwd_q7(qweights[l.name], plan[l.name], h,
-                             backend=backend, rounding=rounding)
-                if not _health._is_tracer(h):
-                    _health._PROBE.observe_output(
-                        h, frac=plan[l.name].out_frac)
-        return h
+                    if not _health._is_tracer(h):
+                        _health._PROBE.observe_output(
+                            h, frac=plan[l.name].out_frac)
+                    return h
+        return _walk(self.layers, x_q, step)
 
     def quantize_input(self, x, plan: PipelinePlan):
         return qf.quantize(x, plan.input_frac)

@@ -121,6 +121,27 @@ class RoutingPlan:
 
 
 @dataclasses.dataclass(frozen=True)
+class LocalRoutingPlan(RoutingPlan):
+    """Local routing with a bias (DeepCaps' 3D capsule layer): a
+    RoutingPlan for the votes and the loop, plus the bias's format.
+    The bias is added to every s in the accumulator's format
+    (uhat_frac + 7), so it is left-shifted by `bias_shift`."""
+    b_frac: int = 0
+    bias_shift: int = 0
+
+
+@dataclasses.dataclass(frozen=True)
+class MergePlan:
+    """A layer that merges the outputs of several layers into one int8
+    tensor (a capsule add, a concat): each input `k` is shifted right
+    by `shifts[k]` (left where negative) from its format `in_fracs[k]`
+    into the common `out_frac`."""
+    in_fracs: tuple
+    out_frac: int
+    shifts: tuple
+
+
+@dataclasses.dataclass(frozen=True)
 class PipelinePlan:
     """The whole network's quantization decision: the input image format
     plus one typed plan per layer, keyed by layer name in walk order."""
@@ -152,7 +173,8 @@ def _register(cls):
     return cls
 
 
-for _cls in (ConvPlan, PrimaryCapsPlan, RoutingPlan):
+for _cls in (ConvPlan, PrimaryCapsPlan, RoutingPlan,
+             LocalRoutingPlan, MergePlan):
     _register(_cls)
 
 

@@ -20,7 +20,7 @@ import pytest
 from jax.sharding import Mesh, SingleDeviceSharding
 
 from repro.kernels import ops
-from repro.kernels.routing import routing_q7_pallas
+from repro.kernels.routing import routing3d_q7_pallas, routing_q7_pallas
 from repro.nn.config import CIFAR10, MNIST, SMALLNORB
 from repro.serving import EDGE_TINY, ModelRegistry
 from repro.serving.sharded import compile_wave, wave_fn
@@ -75,6 +75,41 @@ def test_routing_kernel_compiles(one_chip, geometry, batch):
 
     compiled = jax.jit(route).lower(u_hat).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _kernel_scoped_vmem(hlo: str, kernel: str) -> int:
+    """The scoped VMEM the compiler gave the Mosaic kernel `kernel`."""
+    line, = [ln for ln in hlo.splitlines()
+             if re.match(rf"\s*(ROOT )?%{kernel}\.\d+ = ", ln)
+             and 'custom_call_target="tpu_custom_call"' in ln]
+    size, = re.findall(r'used_scoped_memory_configs\\?":\[\{[^}]*'
+                       r'\\?"size\\?":\\?"(\d+)', line)
+    return int(size)
+
+
+@pytest.mark.parametrize("batch", [1, 64])
+@pytest.mark.parametrize("layer", ["class", "local"])
+def test_deepcaps_routing_kernels_compile(one_chip, layer, batch):
+    """DeepCaps' class routing (J=10, I=2,560, O=32: about 3.3 MB of
+    int32 u_hat a sample) and its local routing with a bias (J=32,
+    I=32, O=8, 16 groups an image), each under its own kernel name,
+    within the default 16 MiB of scoped VMEM."""
+    kernel, (J, I, O), groups = {
+        "class": (routing_q7_pallas, (10, 2560, 32), batch),
+        "local": (routing3d_q7_pallas, (32, 32, 8), 16 * batch)}[layer]
+    u_hat = jax.ShapeDtypeStruct((groups, J, I, O), jnp.int8,
+                                 sharding=one_chip)
+    bias = jax.ShapeDtypeStruct((J, O), jnp.int32, sharding=one_chip) \
+        if layer == "local" else None
+
+    def route(u, b):
+        return kernel(u, b, num_iters=3, caps_out_shifts=(6,) * 3,
+                      caps_out_fracs=(5,) * 3, agree_shifts=(7,) * 2,
+                      logit_frac=5, rounding="floor", interpret=False)
+
+    hlo = jax.jit(route).lower(u_hat, bias).compile().as_text()
+    vmem = _kernel_scoped_vmem(hlo, kernel.__name__)
+    assert 0 < vmem < 16 * 2**20
 
 
 @pytest.mark.parametrize("rows_dim", [(10, 6), (256, 4), (64, 4),
