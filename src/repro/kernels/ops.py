@@ -98,7 +98,8 @@ def routing_q7(u_hat, num_iters: int, caps_out_shifts, caps_out_fracs,
 
     `bias` (int32 [J, O], in the accumulator's format) is added to s
     before each iteration's shift.  `local=True` runs the grouped entry
-    (`routing3d_q7_pallas`: B is then the number of local groups)."""
+    (`routing3d_q7_pallas`: B is then the number of local groups, laid
+    on the lanes, 128 a grid step)."""
     interpret = default_interpret() if interpret is None else interpret
     entry = _routing.routing3d_q7_pallas if local \
         else _routing.routing_q7_pallas

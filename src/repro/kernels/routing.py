@@ -8,6 +8,11 @@ so the entire r-iteration loop fits in VMEM.  This kernel grids over the
 batch, holds u_hat resident, and runs softmax -> weighted-sum -> squash ->
 agreement entirely on-chip, eliminating (2r-1) HBM round-trips of u_hat.
 
+The local (3D) routing of DeepCaps has many small groups instead of a
+few rows with many input capsules, so `routing3d_q7_pallas` lays them
+out differently: the groups on the 128 lanes, 128 a grid step.  Both
+bodies share the elementwise int8 functions below.
+
 Integer semantics match repro.kernels.ref.routing_q7_ref bit-for-bit.
 """
 from __future__ import annotations
@@ -17,10 +22,12 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.quant.int8_ops import SQUASH_GUARD_BITS
 
 INT8_MIN, INT8_MAX = -128, 127
+LANES = 128
 
 
 def _isqrt(n):
@@ -33,9 +40,10 @@ def _isqrt(n):
     return jnp.where(n <= 1, n, jax.lax.fori_loop(0, 32, body, x0))
 
 
-def _squash_caps(s32, in_frac: int, out_frac: int = 7):
-    """Integer squash of each capsule s [J, O, 1] over its O axis."""
-    Q = jnp.sum(s32 * s32, axis=1, keepdims=True)
+def _squash_caps(s32, in_frac: int, out_frac: int = 7, axis: int = 1):
+    """Integer squash of each capsule over its O axis (`axis`): s is
+    [J, O, 1] in the fused kernel, [O, J, groups] in the local one."""
+    Q = jnp.sum(s32 * s32, axis=axis, keepdims=True)
     S = _isqrt(Q)
     P = SQUASH_GUARD_BITS
     shift = out_frac - in_frac + P
@@ -46,12 +54,13 @@ def _squash_caps(s32, in_frac: int, out_frac: int = 7):
     return jnp.clip(jnp.right_shift(ratio * s32, P), INT8_MIN, INT8_MAX)
 
 
-def _softmax_q7_cols(b32, in_frac: int):
-    """Shift-based integer softmax over axis 0 (the J axis of b)."""
-    m = jnp.max(b32, axis=0, keepdims=True)
+def _softmax_q7_cols(b32, in_frac: int, axis: int = 0):
+    """Shift-based integer softmax over the J axis of b (`axis`): b is
+    [J, 1, I] in the fused kernel, [1, J, groups] in the local one."""
+    m = jnp.max(b32, axis=axis, keepdims=True)
     e = jnp.maximum(jnp.right_shift(b32 - m, in_frac), -20)
     p = jnp.left_shift(jnp.ones_like(e), 20 + e)
-    tot = jnp.sum(p, axis=0, keepdims=True)
+    tot = jnp.sum(p, axis=axis, keepdims=True)
     return jnp.clip(jnp.left_shift(p, 7) // jnp.maximum(tot, 1), 0, INT8_MAX)
 
 
@@ -94,27 +103,6 @@ def _routing_kernel(u_ref, *refs, num_iters, caps_out_shifts,
     v_ref[0] = v
 
 
-def _route(u_hat, bias, *, interpret, **plan):
-    """The fused loop over every row of u_hat int8 [B, J, I, O], one row
-    a grid step; the kernel reads it as [B, J, O, I], with I on the
-    lanes (the transpose is an XLA op outside the kernel)."""
-    B, J, I, O = u_hat.shape
-    in_specs = [pl.BlockSpec((1, J, O, I), lambda b: (b, 0, 0, 0))]
-    args = [jnp.swapaxes(u_hat, 2, 3)]
-    if bias is not None:
-        in_specs.append(pl.BlockSpec((J, O, 1), lambda b: (0, 0, 0)))
-        args.append(jnp.asarray(bias, jnp.int32).reshape(J, O, 1))
-    v = pl.pallas_call(
-        functools.partial(_routing_kernel, **plan),
-        grid=(B,),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, J, O, 1), lambda b: (b, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, J, O, 1), jnp.int32),
-        interpret=interpret,
-    )(*args)
-    return v[..., 0].astype(jnp.int8)
-
-
 _STATIC = ("num_iters", "caps_out_shifts", "caps_out_fracs", "agree_shifts",
            "logit_frac", "rounding", "interpret")
 
@@ -126,12 +114,70 @@ def routing_q7_pallas(u_hat, bias=None, *, num_iters: int,
                       interpret: bool):
     """u_hat int8 [B, J, I, O] -> v int8 [B, J, O], all r iterations
     fused, one sample a grid step.  `bias` (int32 [J, O], in the
-    accumulator's format) is added to every s before its shift."""
-    return _route(u_hat, bias, num_iters=num_iters,
-                  caps_out_shifts=caps_out_shifts,
-                  caps_out_fracs=caps_out_fracs, agree_shifts=agree_shifts,
-                  logit_frac=logit_frac, rounding=rounding,
-                  interpret=interpret)
+    accumulator's format) is added to every s before its shift.  The
+    kernel reads u_hat as [B, J, O, I], with I on the lanes (the
+    transpose is an XLA op outside the kernel)."""
+    B, J, I, O = u_hat.shape
+    in_specs = [pl.BlockSpec((1, J, O, I), lambda b: (b, 0, 0, 0))]
+    args = [jnp.swapaxes(u_hat, 2, 3)]
+    if bias is not None:
+        in_specs.append(pl.BlockSpec((J, O, 1), lambda b: (0, 0, 0)))
+        args.append(jnp.asarray(bias, jnp.int32).reshape(J, O, 1))
+    v = pl.pallas_call(
+        functools.partial(_routing_kernel, num_iters=num_iters,
+                          caps_out_shifts=caps_out_shifts,
+                          caps_out_fracs=caps_out_fracs,
+                          agree_shifts=agree_shifts, logit_frac=logit_frac,
+                          rounding=rounding),
+        grid=(B,),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, J, O, 1), lambda b: (b, 0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, J, O, 1), jnp.int32),
+        interpret=interpret,
+    )(*args)
+    return v[..., 0].astype(jnp.int8)
+
+
+def _local_routing_kernel(u_ref, *refs, num_iters, caps_out_shifts,
+                          caps_out_fracs, agree_shifts, logit_frac,
+                          rounding):
+    # The groups sit on the 128 lanes and J on the sublanes: u [I, O, J,
+    # 128] int8, so the softmax over J reduces sublanes of dense tiles,
+    # the sums over I and O add whole vregs over the leading axes, and
+    # the squash's isqrt runs on [1, J, 128] for 128 groups at once.
+    # u is upcast one input capsule i at a time; the logits b [I, J, 128]
+    # live in the VMEM scratch `b_ref`.  `refs` is (v_ref, b_ref) or,
+    # with a bias, (bias_ref, v_ref, b_ref): the bias [O, J, 1] int32 is
+    # already in the accumulator's format and is added to s before its
+    # shift.
+    *bias_ref, v_ref, b_ref = refs
+    I, O, J, Gb = u_ref.shape
+    b_ref[...] = jnp.zeros(b_ref.shape, jnp.int32)
+
+    def u_at(i):
+        return u_ref[i].astype(jnp.int32)                        # [O, J, Gb]
+
+    def weigh(i, s):
+        c = _softmax_q7_cols(b_ref[pl.ds(i, 1)], logit_frac, axis=1)
+        return s + c * u_at(i)                                   # [O, J, Gb]
+
+    v = None
+    for r in range(num_iters):
+        s = jax.lax.fori_loop(0, I, weigh, jnp.zeros((O, J, Gb), jnp.int32))
+        if bias_ref:
+            s = s + bias_ref[0][...]
+        s_q = _rshift_sat8(s, caps_out_shifts[r], rounding)
+        v = _squash_caps(s_q, in_frac=caps_out_fracs[r], axis=0)
+        if r < num_iters - 1:
+            def agree(i, carry):
+                a = jnp.sum(u_at(i) * v, axis=0, keepdims=True)  # [1, J, Gb]
+                a = _rshift_sat8(a, agree_shifts[r], rounding)
+                b = b_ref[pl.ds(i, 1)]
+                b_ref[pl.ds(i, 1)] = jnp.clip(b + a, INT8_MIN, INT8_MAX)
+                return carry
+
+            jax.lax.fori_loop(0, I, agree, 0)
+    v_ref[...] = v.astype(jnp.int8)
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
@@ -140,11 +186,32 @@ def routing3d_q7_pallas(u_hat, bias=None, *, num_iters: int,
                         agree_shifts: tuple, logit_frac: int, rounding: str,
                         interpret: bool):
     """Local routing: u_hat int8 [G, J, I, O] -> v int8 [G, J, O] for G
-    independent groups (one per batch row and output position), one
-    group a grid step.  The same kernel body as `routing_q7_pallas`,
-    under its own name so that a trace keeps the two apart."""
-    return _route(u_hat, bias, num_iters=num_iters,
-                  caps_out_shifts=caps_out_shifts,
-                  caps_out_fracs=caps_out_fracs, agree_shifts=agree_shifts,
-                  logit_frac=logit_frac, rounding=rounding,
-                  interpret=interpret)
+    independent groups (one per batch row and output position), the
+    same integer algorithm as `routing_q7_pallas`.  The kernel reads
+    u_hat as [I, O, J, G], the groups on the lanes, 128 a grid step;
+    G is padded with zero groups to a multiple of 128 and the padding is
+    cut off the answer (the transposes are XLA ops outside the kernel).
+    `bias` (int32 [J, O], in the accumulator's format) is added to
+    every s before its shift."""
+    G, J, I, O = u_hat.shape
+    pad = (-G) % LANES
+    u = jnp.pad(u_hat, ((0, pad), (0, 0), (0, 0), (0, 0)))
+    in_specs = [pl.BlockSpec((I, O, J, LANES), lambda g: (0, 0, 0, g))]
+    args = [u.transpose(2, 3, 1, 0)]
+    if bias is not None:
+        in_specs.append(pl.BlockSpec((O, J, 1), lambda g: (0, 0, 0)))
+        args.append(jnp.asarray(bias, jnp.int32).T.reshape(O, J, 1))
+    v = pl.pallas_call(
+        functools.partial(_local_routing_kernel, num_iters=num_iters,
+                          caps_out_shifts=caps_out_shifts,
+                          caps_out_fracs=caps_out_fracs,
+                          agree_shifts=agree_shifts, logit_frac=logit_frac,
+                          rounding=rounding),
+        grid=((G + pad) // LANES,),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((O, J, LANES), lambda g: (0, 0, g)),
+        out_shape=jax.ShapeDtypeStruct((O, J, G + pad), jnp.int8),
+        scratch_shapes=[pltpu.VMEM((I, J, LANES), jnp.int32)],
+        interpret=interpret,
+    )(*args)
+    return v[..., :G].transpose(2, 1, 0)

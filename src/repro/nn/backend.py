@@ -188,8 +188,9 @@ class PallasBackend(JnpBackend):
                                    bias=bias, local=False)
 
     def local_routing_q7(self, u_hat, plan, *, rounding, bias=None):
-        """The grouped entry (`routing3d_q7_pallas`), one group a grid
-        step; its fallbacks count under the op "routing3d"."""
+        """The grouped entry (`routing3d_q7_pallas`), the groups on the
+        128 lanes, 128 a grid step; its fallbacks count under the op
+        "routing3d"."""
         return self._fused_routing("routing3d", u_hat, plan,
                                    rounding=rounding, bias=bias, local=True)
 

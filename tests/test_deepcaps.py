@@ -184,22 +184,34 @@ def test_routing_with_a_zero_bias_is_the_bias_free_routing(tiny, backend):
             np.asarray(kernel(u_hat, **kw)))
 
 
-def test_local_routing_is_the_class_routing_group_by_group(tiny):
+@pytest.mark.parametrize("with_bias", [False, True])
+@pytest.mark.parametrize("groups", [1, 5, 130])
+def test_local_routing_is_the_class_routing_group_by_group(tiny, groups,
+                                                           with_bias):
+    """The local kernel lays 128 groups on the lanes a grid step: below
+    one block, an odd count and a block plus a remainder.  u_hat spans
+    the whole int8 range and the bias is large, so the saturating
+    shifts of s and of the agreement engage in every case."""
     *_, qnet = tiny
-    plan, u_hat, bias = _local_case(qnet, groups=5, seed=1)
+    plan = qnet.plan["cell4/skip3d"]
+    rng = np.random.default_rng(groups)
+    u_hat = jnp.asarray(rng.integers(-128, 128, (groups, 4, 4, 4)), jnp.int8)
+    bias = jnp.asarray(rng.integers(-4000, 4001, (4, 4)), jnp.int32) \
+        if with_bias else None
     pal = BACKENDS["pallas"]
     local = np.asarray(pal.local_routing_q7(u_hat, plan, rounding="floor",
                                             bias=bias))
     per_group = np.concatenate([
         np.asarray(pal.routing_q7(u_hat[g:g + 1], plan, rounding="floor",
-                                  bias=bias)) for g in range(5)])
+                                  bias=bias)) for g in range(groups)])
     np.testing.assert_array_equal(local, per_group)
     np.testing.assert_array_equal(
         local, np.asarray(BACKENDS["jnp"].routing_q7(
             u_hat, plan, rounding="floor", bias=bias)))
-    # the bias moves the answer: the comparison above is not vacuous
-    assert not np.array_equal(local, np.asarray(pal.local_routing_q7(
-        u_hat, plan, rounding="floor")))
+    if with_bias:
+        # the bias moves the answer: the comparison is not vacuous
+        assert not np.array_equal(local, np.asarray(pal.local_routing_q7(
+            u_hat, plan, rounding="floor")))
 
 
 def test_a_non_default_local_routing_falls_back_counted(tiny):

@@ -93,7 +93,8 @@ def test_deepcaps_routing_kernels_compile(one_chip, layer, batch):
     """DeepCaps' class routing (J=10, I=2,560, O=32: about 3.3 MB of
     int32 u_hat a sample) and its local routing with a bias (J=32,
     I=32, O=8, 16 groups an image), each under its own kernel name,
-    within the default 16 MiB of scoped VMEM."""
+    within the default 16 MiB of scoped VMEM; the local one also with
+    under 16 MiB of HBM temporaries."""
     kernel, (J, I, O), groups = {
         "class": (routing_q7_pallas, (10, 2560, 32), batch),
         "local": (routing3d_q7_pallas, (32, 32, 8), 16 * batch)}[layer]
@@ -107,9 +108,12 @@ def test_deepcaps_routing_kernels_compile(one_chip, layer, batch):
                       caps_out_fracs=(5,) * 3, agree_shifts=(7,) * 2,
                       logit_frac=5, rounding="floor", interpret=False)
 
-    hlo = jax.jit(route).lower(u_hat, bias).compile().as_text()
-    vmem = _kernel_scoped_vmem(hlo, kernel.__name__)
+    compiled = jax.jit(route).lower(u_hat, bias).compile()
+    vmem = _kernel_scoped_vmem(compiled.as_text(), kernel.__name__)
     assert 0 < vmem < 16 * 2**20
+    if layer == "local":
+        # groups on the lanes: no tile-padded copy of u_hat in HBM
+        assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**20
 
 
 @pytest.mark.parametrize("rows_dim", [(10, 6), (256, 4), (64, 4),
