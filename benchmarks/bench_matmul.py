@@ -14,7 +14,8 @@ import numpy as np
 
 from benchmarks import util
 from benchmarks.util import csv_row, time_call
-from repro.kernels import ops, ref
+from repro.kernels import ops
+from repro.quant import int8_ops
 
 SHAPES = [(20, 30, 40), (128, 128, 128), (256, 256, 256)]
 
@@ -28,7 +29,7 @@ def main():
         bf = b.astype(jnp.float32)
         macs = M * K * N
 
-        f = jax.jit(lambda x, y: ref.matmul_q7(x, y, 7))
+        f = jax.jit(lambda x, y: int8_ops.matmul_q7(x, y, 7))
         us = time_call(f, a, b)
         csv_row(f"matmul_q7_xla_{M}x{K}x{N}", us, f"{macs/us:.0f}MAC/us")
 

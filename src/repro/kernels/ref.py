@@ -1,20 +1,14 @@
-"""Pure-jnp oracles for every Pallas kernel in this package.
-
-The integer semantics live in repro.quant.int8_ops (the quantization
-framework and the kernels must agree bit-for-bit); this module re-exports
-them under kernel-facing names and adds the per-channel W8A8 reference.
+"""Pure-jnp references of the kernels no served path runs (the W8A8
+matmul and the float squash).  The served kernels' integer semantics
+live in repro.quant.int8_ops, which their bodies call.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-from repro.quant.int8_ops import (  # noqa: F401  (re-exported oracles)
-    INT8_MAX, INT8_MIN, add_q7, conv2d_q7, isqrt_newton, matmul_q7,
-    matmul_q7_acc, relu_q7, rshift_sat8, softmax_q7, softmax_q7_precise,
-    squash_q7,
-)
 from repro.core.routing import squash as squash_float_ref  # noqa: F401
+from repro.quant.int8_ops import INT8_MAX, INT8_MIN
 
 
 def w8a8_matmul_ref(a, w, col_shift, rounding: str = "nearest"):
@@ -28,30 +22,3 @@ def w8a8_matmul_ref(a, w, col_shift, rounding: str = "nearest"):
     acc = jnp.where(sh >= 0, jnp.right_shift(acc, jnp.maximum(sh, 0)),
                     jnp.left_shift(acc, jnp.maximum(-sh, 0)))
     return jnp.clip(acc, INT8_MIN, INT8_MAX).astype(jnp.int8)
-
-
-def routing_q7_ref(u_hat, num_iters: int, caps_out_shifts, caps_out_fracs,
-                   agree_shifts, logit_frac: int, rounding: str = "floor",
-                   softmax_impl: str = "q7"):
-    """Fused dynamic-routing oracle (Alg. 5 inner loop, int8).
-
-    u_hat int8 [B, J, I, O] -> v int8 [B, J, O] (Q0.7).
-    """
-    from repro.nn.variants import REGISTRY
-    from repro.quant import int8_ops as q
-    B, J, I, O = u_hat.shape
-    sm = REGISTRY.get("softmax", softmax_impl).q7
-    b = jnp.zeros((B, J, I), jnp.int8)
-    v = None
-    for r in range(num_iters):
-        c = sm(b.swapaxes(1, 2), in_frac=logit_frac).swapaxes(1, 2)
-        acc = jnp.einsum("bji,bjio->bjo", c.astype(jnp.int32),
-                         u_hat.astype(jnp.int32))
-        s_q = q.rshift_sat8(acc, caps_out_shifts[r], rounding)
-        v = q.squash_q7(s_q, in_frac=caps_out_fracs[r], out_frac=7)
-        if r < num_iters - 1:
-            acc = jnp.einsum("bjio,bjo->bji", u_hat.astype(jnp.int32),
-                             v.astype(jnp.int32))
-            a = q.rshift_sat8(acc, agree_shifts[r], rounding)
-            b = q.add_q7(b, a)
-    return v

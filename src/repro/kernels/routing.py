@@ -11,9 +11,10 @@ agreement entirely on-chip, eliminating (2r-1) HBM round-trips of u_hat.
 The local (3D) routing of DeepCaps has many small groups instead of a
 few rows with many input capsules, so `routing3d_q7_pallas` lays them
 out differently: the groups on the 128 lanes, 128 a grid step.  Both
-bodies share the elementwise int8 functions below.
-
-Integer semantics match repro.kernels.ref.routing_q7_ref bit-for-bit.
+bodies call the int32 forms of repro.quant.int8_ops' requantize, softmax
+and squash, reduced along their own layout's axes, so their integer
+semantics are the oracle's (`nn.backend.JnpBackend.routing_q7`)
+bit-for-bit.
 """
 from __future__ import annotations
 
@@ -24,54 +25,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.quant.int8_ops import SQUASH_GUARD_BITS
+from repro.quant import int8_ops as q
 
-INT8_MIN, INT8_MAX = -128, 127
 LANES = 128
-
-
-def _isqrt(n):
-    x0 = jnp.maximum(n // 2, 1)
-
-    def body(_, x):
-        nxt = (x + n // jnp.maximum(x, 1)) // 2
-        return jnp.where(nxt < x, nxt, x)
-
-    return jnp.where(n <= 1, n, jax.lax.fori_loop(0, 32, body, x0))
-
-
-def _squash_caps(s32, in_frac: int, out_frac: int = 7, axis: int = 1):
-    """Integer squash of each capsule over its O axis (`axis`): s is
-    [J, O, 1] in the fused kernel, [O, J, groups] in the local one."""
-    Q = jnp.sum(s32 * s32, axis=axis, keepdims=True)
-    S = _isqrt(Q)
-    P = SQUASH_GUARD_BITS
-    shift = out_frac - in_frac + P
-    num = jnp.left_shift(S, shift) if shift >= 0 \
-        else jnp.right_shift(S, -shift)
-    den = (1 << in_frac) + jnp.right_shift(Q, in_frac)
-    ratio = num // jnp.maximum(den, 1)
-    return jnp.clip(jnp.right_shift(ratio * s32, P), INT8_MIN, INT8_MAX)
-
-
-def _softmax_q7_cols(b32, in_frac: int, axis: int = 0):
-    """Shift-based integer softmax over the J axis of b (`axis`): b is
-    [J, 1, I] in the fused kernel, [1, J, groups] in the local one."""
-    m = jnp.max(b32, axis=axis, keepdims=True)
-    e = jnp.maximum(jnp.right_shift(b32 - m, in_frac), -20)
-    p = jnp.left_shift(jnp.ones_like(e), 20 + e)
-    tot = jnp.sum(p, axis=axis, keepdims=True)
-    return jnp.clip(jnp.left_shift(p, 7) // jnp.maximum(tot, 1), 0, INT8_MAX)
-
-
-def _rshift_sat8(acc, shift: int, rounding: str):
-    if shift > 0:
-        if rounding == "nearest":
-            acc = acc + (1 << (shift - 1))
-        acc = jnp.right_shift(acc, shift)
-    elif shift < 0:
-        acc = jnp.left_shift(acc, -shift)
-    return jnp.clip(acc, INT8_MIN, INT8_MAX)
 
 
 def _routing_kernel(u_ref, *refs, num_iters, caps_out_shifts,
@@ -90,16 +46,16 @@ def _routing_kernel(u_ref, *refs, num_iters, caps_out_shifts,
     b = jnp.zeros((J, 1, I), jnp.int32)
     v = None
     for r in range(num_iters):
-        c = _softmax_q7_cols(b, logit_frac)                      # [J, 1, I]
+        c = q.softmax_q7_i32(b, logit_frac, axis=0)              # [J, 1, I]
         s = jnp.sum(c * u, axis=2, keepdims=True)                # [J, O, 1]
         if bias_ref:
             s = s + bias_ref[0][...]
-        s_q = _rshift_sat8(s, caps_out_shifts[r], rounding)
-        v = _squash_caps(s_q, in_frac=caps_out_fracs[r])         # [J, O, 1]
+        s_q = q.rshift_sat8_i32(s, caps_out_shifts[r], rounding)
+        v = q.squash_q7_i32(s_q, caps_out_fracs[r], axis=1)      # [J, O, 1]
         if r < num_iters - 1:
             a = jnp.sum(u * v, axis=1, keepdims=True)            # [J, 1, I]
-            a = _rshift_sat8(a, agree_shifts[r], rounding)
-            b = jnp.clip(b + a, INT8_MIN, INT8_MAX)              # q7 add
+            a = q.rshift_sat8_i32(a, agree_shifts[r], rounding)
+            b = jnp.clip(b + a, q.INT8_MIN, q.INT8_MAX)          # q7 add
     v_ref[0] = v
 
 
@@ -158,7 +114,7 @@ def _local_routing_kernel(u_ref, *refs, num_iters, caps_out_shifts,
         return u_ref[i].astype(jnp.int32)                        # [O, J, Gb]
 
     def weigh(i, s):
-        c = _softmax_q7_cols(b_ref[pl.ds(i, 1)], logit_frac, axis=1)
+        c = q.softmax_q7_i32(b_ref[pl.ds(i, 1)], logit_frac, axis=1)
         return s + c * u_at(i)                                   # [O, J, Gb]
 
     v = None
@@ -166,14 +122,14 @@ def _local_routing_kernel(u_ref, *refs, num_iters, caps_out_shifts,
         s = jax.lax.fori_loop(0, I, weigh, jnp.zeros((O, J, Gb), jnp.int32))
         if bias_ref:
             s = s + bias_ref[0][...]
-        s_q = _rshift_sat8(s, caps_out_shifts[r], rounding)
-        v = _squash_caps(s_q, in_frac=caps_out_fracs[r], axis=0)
+        s_q = q.rshift_sat8_i32(s, caps_out_shifts[r], rounding)
+        v = q.squash_q7_i32(s_q, caps_out_fracs[r], axis=0)
         if r < num_iters - 1:
             def agree(i, carry):
                 a = jnp.sum(u_at(i) * v, axis=0, keepdims=True)  # [1, J, Gb]
-                a = _rshift_sat8(a, agree_shifts[r], rounding)
+                a = q.rshift_sat8_i32(a, agree_shifts[r], rounding)
                 b = b_ref[pl.ds(i, 1)]
-                b_ref[pl.ds(i, 1)] = jnp.clip(b + a, INT8_MIN, INT8_MAX)
+                b_ref[pl.ds(i, 1)] = jnp.clip(b + a, q.INT8_MIN, q.INT8_MAX)
                 return carry
 
             jax.lax.fori_loop(0, I, agree, 0)

@@ -4,11 +4,12 @@ Lane-dense: the kernel sees the capsules as `[D, rows, 128]` int8, each
 capsule on one of the 128 lanes and its D components (4-8 in the paper)
 on the leading axis; `ops.squash_q7` lays `[..., D]` out so, padding
 with zero capsules.  Each grid step loads a `(D, rb, 128)` tile
-into VMEM, sums the squares over the leading axis (plain vector adds),
-runs the fixed-iteration Newton-Raphson integer sqrt and the guarded
-power-of-two ratio on dense `[rb, 128]` int32 tiles, and writes
-int8 back in the same layout.  `tiling` picks the block from the number
-of capsules and D.
+into VMEM and runs `repro.quant.int8_ops.squash_q7` over the leading
+axis, the oracle's arithmetic: the sum of squares is plain vector adds,
+and the fixed-iteration Newton-Raphson integer sqrt and the guarded
+power-of-two ratio run on dense `[rb, 128]` int32 tiles; int8 is
+written back in the same layout.  `tiling` picks the block from the
+number of capsules and D.
 """
 from __future__ import annotations
 
@@ -18,21 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.quant.int8_ops import SQUASH_GUARD_BITS
-
-INT8_MIN, INT8_MAX = -128, 127
-
-
-def _isqrt(n):
-    x0 = jnp.maximum(n // 2, 1)
-
-    def body(_, x):
-        nxt = (x + n // jnp.maximum(x, 1)) // 2
-        return jnp.where(nxt < x, nxt, x)
-
-    x = jax.lax.fori_loop(0, 32, body, x0)
-    return jnp.where(n <= 1, n, x)
-
+from repro.quant.int8_ops import squash_q7
 
 LANES = 128
 _SUBLANES_I8 = 32           # int8 tile: (32, 128)
@@ -55,17 +42,7 @@ def tiling(rows: int, D: int) -> tuple[int, int]:
 
 
 def _squash_kernel(s_ref, o_ref, *, in_frac: int, out_frac: int):
-    s = s_ref[...].astype(jnp.int32)            # [D, rb, 128]
-    Q = jnp.sum(s * s, axis=0, keepdims=True)   # [1, rb, 128]
-    S = _isqrt(Q)
-    P = SQUASH_GUARD_BITS
-    shift = out_frac - in_frac + P
-    num = jnp.left_shift(S, shift) if shift >= 0 \
-        else jnp.right_shift(S, -shift)
-    den = (1 << in_frac) + jnp.right_shift(Q, in_frac)
-    ratio = num // jnp.maximum(den, 1)
-    v = jnp.right_shift(ratio * s, P)
-    o_ref[...] = jnp.clip(v, INT8_MIN, INT8_MAX).astype(jnp.int8)
+    o_ref[...] = squash_q7(s_ref[...], in_frac, out_frac, axis=0)
 
 
 @functools.partial(jax.jit, static_argnames=("in_frac", "out_frac",

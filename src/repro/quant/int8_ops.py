@@ -1,9 +1,14 @@
 """Exact int8 operation semantics (the pure-jnp oracle layer).
 
-These functions define the integer arithmetic the Pallas kernels must
-reproduce bit-exactly (kernels/ref.py re-exports them): int8 operands,
-int32 accumulation, power-of-two rescale (arithmetic shift), saturation to
-[-128, 127] — the TPU analogue of the paper's CMSIS-NN / PULP-NN kernels.
+These functions define the integer arithmetic of the quantized path:
+int8 operands, int32 accumulation, power-of-two rescale (arithmetic
+shift), saturation to [-128, 127] — the TPU analogue of the paper's
+CMSIS-NN / PULP-NN kernels.  The Pallas kernel bodies (kernels/routing.py,
+kernels/squash.py) call the requantize, squash and softmax below, so
+there is one definition of each.  The `*_i32` forms are the same
+arithmetic without the final int8 cast, for kernels that keep their
+state in int32 between steps; the squash and the shift softmax reduce
+over `axis` (the oracle's last axis, a kernel's layout axis).
 
 `rounding="floor"` matches the paper/CMSIS `__SSAT(sum >> shift, 8)`
 truncation; `rounding="nearest"` adds the half-LSB before shifting
@@ -19,10 +24,8 @@ from repro.obs import numerics as _health
 INT8_MIN, INT8_MAX = -128, 127
 
 
-def rshift_sat8(acc, shift: int, rounding: str = "floor"):
-    """int32 accumulator -> int8 via arithmetic shift + saturate."""
-    if _health._PROBE is not None:     # observer only; skips jit tracers
-        _health.observe_requant(acc, shift, rounding)
+def rshift_sat8_i32(acc, shift: int, rounding: str = "floor"):
+    """`rshift_sat8` with the saturated result left in int32."""
     acc = acc.astype(jnp.int32)
     if shift > 0:
         if rounding == "nearest":
@@ -30,7 +33,14 @@ def rshift_sat8(acc, shift: int, rounding: str = "floor"):
         acc = jnp.right_shift(acc, shift)
     elif shift < 0:
         acc = jnp.left_shift(acc, -shift)
-    return jnp.clip(acc, INT8_MIN, INT8_MAX).astype(jnp.int8)
+    return jnp.clip(acc, INT8_MIN, INT8_MAX)
+
+
+def rshift_sat8(acc, shift: int, rounding: str = "floor"):
+    """int32 accumulator -> int8 via arithmetic shift + saturate."""
+    if _health._PROBE is not None:     # observer only; skips jit tracers
+        _health.observe_requant(acc, shift, rounding)
+    return rshift_sat8_i32(acc, shift, rounding).astype(jnp.int8)
 
 
 def sat8(x):
@@ -148,17 +158,35 @@ def isqrt_newton(n):
         nxt = (x + n // jnp.maximum(x, 1)) // 2
         return jnp.where(nxt < x, nxt, x)
 
-    x = jax.lax.fori_loop(0, 32, body, x0)
-    # n in {0,1}: x0 heuristics
-    x = jnp.where(n <= 1, n, x)
-    return x
+    # n in {0,1}: x0 heuristics.  The compare is traced before the loop:
+    # the order of the ops is part of the kernels' compiled code.
+    return jnp.where(n <= 1, n, jax.lax.fori_loop(0, 32, body, x0))
 
 
 SQUASH_GUARD_BITS = 10
 
 
-def squash_q7(s, in_frac: int, out_frac: int = 7):
-    """Integer squash (paper Eq. 8) over the last axis.
+def _squash_scale(s32, norm, Q, in_frac: int, out_frac: int):
+    """sat8((ratio * s) >> P) with Eq. 8's guarded factor
+    ratio = (norm << (o - i + P)) // ((1 << i) + (Q >> i)), in int32."""
+    P = SQUASH_GUARD_BITS
+    shift = out_frac - in_frac + P
+    num = jnp.left_shift(norm, shift) if shift >= 0 \
+        else jnp.right_shift(norm, -shift)
+    den = (1 << in_frac) + jnp.right_shift(Q, in_frac)
+    ratio = num // jnp.maximum(den, 1)
+    return jnp.clip(jnp.right_shift(ratio * s32, P), INT8_MIN, INT8_MAX)
+
+
+def squash_q7_i32(s, in_frac: int, out_frac: int = 7, axis: int = -1):
+    """`squash_q7` with the saturated result left in int32."""
+    s32 = s.astype(jnp.int32)
+    Q = jnp.sum(s32 * s32, axis=axis, keepdims=True)
+    return _squash_scale(s32, isqrt_newton(Q), Q, in_frac, out_frac)
+
+
+def squash_q7(s, in_frac: int, out_frac: int = 7, axis: int = -1):
+    """Integer squash (paper Eq. 8) of the capsules along `axis`.
 
     s int8 [..., D] with `in_frac` (i) fractional bits; returns int8 with
     `out_frac` (o) fractional bits.  Derivation: with Q = sum(s^2) (2i frac
@@ -172,34 +200,32 @@ def squash_q7(s, in_frac: int, out_frac: int = 7):
         v     = sat8((ratio * s) >> P)
     Values: S <= 127*sqrt(D) < 2^9 for D <= 16, so int32 never overflows.
     """
-    s32 = s.astype(jnp.int32)
-    Q = jnp.sum(s32 * s32, axis=-1, keepdims=True)
-    S = isqrt_newton(Q)
-    P = SQUASH_GUARD_BITS
-    shift = out_frac - in_frac + P
-    num = jnp.left_shift(S, max(shift, 0)) if shift >= 0 \
-        else jnp.right_shift(S, -shift)
-    den = (1 << in_frac) + jnp.right_shift(Q, in_frac)
-    ratio = num // jnp.maximum(den, 1)
-    v = jnp.right_shift(ratio * s32, P)
-    return jnp.clip(v, INT8_MIN, INT8_MAX).astype(jnp.int8)
+    return squash_q7_i32(s, in_frac, out_frac, axis).astype(jnp.int8)
 
 
-def softmax_q7(x, in_frac: int):
-    """Shift-based integer softmax over the last axis -> Q0.7 output.
+def _exp2_shift(x, in_frac: int, axis: int):
+    """2^(20 + floor(x - max)) per element (floored at 2^0) and its sum
+    along `axis` (<= n * 2^20, fits int32)."""
+    x32 = x.astype(jnp.int32)
+    m = jnp.max(x32, axis=axis, keepdims=True)
+    e = jnp.maximum(jnp.right_shift(x32 - m, in_frac), -20)   # <= 0
+    p = jnp.left_shift(jnp.ones_like(e), 20 + e)
+    return p, jnp.sum(p, axis=axis, keepdims=True)
+
+
+def softmax_q7_i32(x, in_frac: int, axis: int = -1):
+    """`softmax_q7` with the saturated result left in int32."""
+    p, tot = _exp2_shift(x, in_frac, axis)
+    return jnp.clip(jnp.left_shift(p, 7) // jnp.maximum(tot, 1), 0, INT8_MAX)
+
+
+def softmax_q7(x, in_frac: int, axis: int = -1):
+    """Shift-based integer softmax along `axis` -> Q0.7 output.
 
     Faithful to the arm_softmax_q7 approach: probabilities are powers of two
     of the integer part of (x - max), normalized to 128 = 1.0, saturated to
     127.  Coarse but branch/LUT-free."""
-    x32 = x.astype(jnp.int32)
-    m = jnp.max(x32, axis=-1, keepdims=True)
-    # integer exponent of 2^(x-m) in value units
-    e = jnp.right_shift(x32 - m, in_frac)          # <= 0
-    e = jnp.maximum(e, -20)
-    p = jnp.left_shift(jnp.ones_like(e), 20 + e)   # 2^(20+e)
-    tot = jnp.sum(p, axis=-1, keepdims=True)   # <= n_cls * 2^20, fits int32
-    c = jnp.left_shift(p, 7) // jnp.maximum(tot, 1)
-    return jnp.clip(c, 0, INT8_MAX).astype(jnp.int8)
+    return softmax_q7_i32(x, in_frac, axis).astype(jnp.int8)
 
 
 def softmax_q7_precise(x, in_frac: int):
@@ -230,11 +256,7 @@ def softmax_q7_approx(x, in_frac: int):
     `softmax_q7`, but the normalizer sum is rounded UP to a power of two
     (2^ceil(log2(sum))), so the per-element integer division becomes one
     arithmetic right shift — the cheapest softmax an MCU can run."""
-    x32 = x.astype(jnp.int32)
-    m = jnp.max(x32, axis=-1, keepdims=True)
-    e = jnp.maximum(jnp.right_shift(x32 - m, in_frac), -20)
-    p = jnp.left_shift(jnp.ones_like(e), 20 + e)
-    tot = jnp.sum(p, axis=-1, keepdims=True)
+    p, tot = _exp2_shift(x, in_frac, -1)
     k = ceil_log2_int(tot)          # >= 20: the max element contributes 2^20
     c = jnp.right_shift(p, k - 7)
     return jnp.clip(c, 0, INT8_MAX).astype(jnp.int8)
@@ -252,12 +274,4 @@ def squash_q7_approx(s, in_frac: int, out_frac: int = 7):
     ordering; the factor error is bounded by the capsule dimension."""
     s32 = s.astype(jnp.int32)
     M = jnp.max(jnp.abs(s32), axis=-1, keepdims=True)
-    Q = M * M
-    P = SQUASH_GUARD_BITS
-    shift = out_frac - in_frac + P
-    num = jnp.left_shift(M, max(shift, 0)) if shift >= 0 \
-        else jnp.right_shift(M, -shift)
-    den = (1 << in_frac) + jnp.right_shift(Q, in_frac)
-    ratio = num // jnp.maximum(den, 1)
-    v = jnp.right_shift(ratio * s32, P)
-    return jnp.clip(v, INT8_MIN, INT8_MAX).astype(jnp.int8)
+    return _squash_scale(s32, M, M * M, in_frac, out_frac).astype(jnp.int8)

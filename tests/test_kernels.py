@@ -1,5 +1,6 @@
-"""Per-kernel validation: Pallas (interpret mode) vs pure-jnp ref oracles,
-swept over shapes / shifts / rounding modes.  Integer kernels must match
+"""Per-kernel validation: Pallas (interpret mode) vs the pure-jnp oracles
+(repro.quant.int8_ops, the jnp backend's routing loop), swept over
+shapes / shifts / rounding modes.  Integer kernels must match
 BIT-EXACTLY; float kernels to allclose."""
 import jax
 import jax.numpy as jnp
@@ -7,7 +8,10 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
+from repro.nn.backend import JnpBackend
 from repro.nn.config import CIFAR10, MNIST, SMALLNORB
+from repro.nn.plans import RoutingPlan
+from repro.quant import int8_ops as q
 from repro.serving import EDGE_TINY
 
 RNG = np.random.default_rng(42)
@@ -25,14 +29,14 @@ def test_q7_matmul_exact(mkn, shift, rounding):
     M, K, N = mkn
     a, b = i8((M, K)), i8((K, N))
     got = ops.matmul_q7(a, b, shift, rounding)
-    want = ref.matmul_q7(a, b, shift, rounding)
+    want = q.matmul_q7(a, b, shift, rounding)
     np.testing.assert_array_equal(got, want)
 
 
 def test_q7_matmul_negative_shift():
     a, b = i8((8, 8)), i8((8, 8))
     got = ops.matmul_q7(a, b, -2)
-    want = ref.matmul_q7(a, b, -2)
+    want = q.matmul_q7(a, b, -2)
     np.testing.assert_array_equal(got, want)
 
 
@@ -41,11 +45,11 @@ def test_bmm_q7(batch):
     a = i8(batch + (12, 20))
     b = i8(batch + (20, 8))
     got = ops.bmm_q7(a, b, 4)
-    want = ref.matmul_q7(a, b, 4) if not batch else None
+    want = q.matmul_q7(a, b, 4) if not batch else None
     # oracle: einsum per batch
     acc = jnp.einsum("...mk,...kn->...mn", a.astype(jnp.int32),
                      b.astype(jnp.int32))
-    want = ref.rshift_sat8(acc, 4)
+    want = q.rshift_sat8(acc, 4)
     np.testing.assert_array_equal(got, want)
 
 
@@ -58,7 +62,7 @@ def test_squash_q7_exact(rd, in_frac):
     R, D = rd
     s = i8((R, D))
     got = ops.squash_q7(s, in_frac=in_frac)
-    want = ref.squash_q7(s, in_frac=in_frac)
+    want = q.squash_q7(s, in_frac=in_frac)
     np.testing.assert_array_equal(got, want)
 
 
@@ -78,7 +82,7 @@ SERVED_CAPS = {f"{name}-b{b}": (b, cfg.num_input_caps, cfg.pcap_dim)
 def test_squash_q7_batched_shape(shape, fill):
     s = i8(shape) if fill is None else jnp.full(shape, fill, jnp.int8)
     got = ops.squash_q7(s, in_frac=5)
-    want = ref.squash_q7(s, in_frac=5)
+    want = q.squash_q7(s, in_frac=5)
     assert got.shape == s.shape
     np.testing.assert_array_equal(got, want)
 
@@ -90,8 +94,9 @@ def test_routing_fused_exact(rounding):
     kw = dict(num_iters=3, caps_out_shifts=(8, 9, 9),
               caps_out_fracs=(7, 6, 6), agree_shifts=(8, 8), logit_frac=7)
     got = ops.routing_q7(u, rounding=rounding, **kw)
-    want = ref.routing_q7_ref(u, 3, (8, 9, 9), (7, 6, 6), (8, 8), 7,
-                              rounding=rounding)
+    plan = RoutingPlan(uhat_shift=0, logit_frac=7, caps_out_shifts=(8, 9, 9),
+                       caps_out_fracs=(7, 6, 6), agree_shifts=(8, 8))
+    want = JnpBackend().routing_q7(u, plan, rounding=rounding)
     np.testing.assert_array_equal(got, want)
 
 
@@ -116,7 +121,7 @@ def test_routing_fused_matches_unfused_capsule_layer():
     # fused path: compute u_hat the same way, then one kernel call
     acc = jnp.einsum("jiod,bid->bjio", W.astype(jnp.int32),
                      u.astype(jnp.int32))
-    u_hat = ref.rshift_sat8(acc, shifts["uhat_shift"])
+    u_hat = q.rshift_sat8(acc, shifts["uhat_shift"])
     got = ops.routing_q7(u_hat, num_iters=3, caps_out_shifts=(9, 9, 9),
                          caps_out_fracs=(7, 7, 7), agree_shifts=(8, 8),
                          logit_frac=7)
@@ -143,6 +148,6 @@ def test_squash_float_close():
 def test_isqrt_exact_floor_sqrt():
     n = jnp.asarray([0, 1, 2, 3, 4, 8, 15, 16, 17, 1023, 1024, 1 << 20,
                      (1 << 30) + 12345], jnp.int32)
-    got = ref.isqrt_newton(n)
+    got = q.isqrt_newton(n)
     want = jnp.asarray([int(np.sqrt(float(v))) for v in n], jnp.int32)
     np.testing.assert_array_equal(got, want)

@@ -85,6 +85,32 @@ def test_squash_q7_norm_bounded(seed, D, in_frac):
     assert ((v == 0) | (np.sign(v) == np.sign(sn))).all()
 
 
+@pytest.mark.parametrize("axis", [0, 1])
+@pytest.mark.parametrize("op", ["squash", "softmax"])
+@settings(max_examples=15, deadline=None)
+@given(st.integers(min_value=0, max_value=1000),
+       st.integers(min_value=3, max_value=9))
+def test_int8_ops_axis_and_int32_forms(op, axis, seed, frac):
+    """Reducing along `axis` is moving that axis last and reducing there
+    (the kernels' layouts against the oracle's), and each int32 form,
+    saturated and cast, is its int8 function (requantize included)."""
+    q7, q32 = {"squash": (q.squash_q7, q.squash_q7_i32),
+               "softmax": (q.softmax_q7, q.softmax_q7_i32)}[op]
+    rng = np.random.default_rng(seed)
+    x = jnp.asarray(rng.integers(-128, 128, (5, 6, 7)), jnp.int8)
+    want = jnp.moveaxis(q7(jnp.moveaxis(x, axis, -1), frac), -1, axis)
+    np.testing.assert_array_equal(q7(x, frac, axis=axis), want)
+    wide = q32(x.astype(jnp.int32), frac, axis=axis)
+    assert wide.dtype == jnp.int32
+    np.testing.assert_array_equal(q.sat8(wide), want)
+    acc = jnp.asarray(rng.integers(-2**20, 2**20, (5, 6, 7)), jnp.int32)
+    for shift in (-2, 0, frac):
+        for rounding in ("floor", "nearest"):
+            np.testing.assert_array_equal(
+                q.sat8(q.rshift_sat8_i32(acc, shift, rounding)),
+                q.rshift_sat8(acc, shift, rounding))
+
+
 @settings(max_examples=50, deadline=None)
 @given(st.integers(min_value=0, max_value=10_000))
 def test_matmul_q7_dequant_close_to_float(seed):
