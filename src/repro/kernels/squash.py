@@ -6,8 +6,8 @@ on the leading axis; `ops.squash_q7` lays `[..., D]` out so, padding
 with zero capsules.  Each grid step loads a `(D, rb, 128)` tile
 into VMEM and runs `repro.quant.int8_ops.squash_q7` over the leading
 axis, the oracle's arithmetic: the sum of squares is plain vector adds,
-and the fixed-iteration Newton-Raphson integer sqrt and the guarded
-power-of-two ratio run on dense `[rb, 128]` int32 tiles; int8 is
+and the loop-free, division-free integer sqrt (`int8_ops.isqrt`) and the
+guarded power-of-two ratio run on dense `[rb, 128]` int32 tiles; int8 is
 written back in the same layout.  `tiling` picks the block from the
 number of capsules and D.
 """

@@ -142,25 +142,32 @@ def relu_q7(x):
 
 
 # ---------------------------------------------------------------------------
-# integer square root (Newton-Raphson, paper Alg. 4) and squash (Eq. 8)
+# integer square root (paper Alg. 4's result) and squash (Eq. 8)
 # ---------------------------------------------------------------------------
-def isqrt_newton(n):
-    """Integer sqrt of int32 n (elementwise, vectorized Newton-Raphson).
+_ISQRT_MAX = 46340          # floor(sqrt(2**31 - 1)): y * y fits int32
 
-    Follows Alg. 4: x0 = n/2, x_{k+1} = (x_k + n/x_k)/2, stop when the next
-    iterate stops decreasing.  A fixed 32-iteration loop (Newton from n/2
-    halves the exponent gap per step; 32 covers any int32) with the
-    monotonicity guard makes it bit-exact with the sequential algorithm."""
+
+def isqrt(n):
+    """floor(sqrt(n)) of int32 n >= 0, elementwise: Alg. 4's result.
+
+    Alg. 4 runs Newton-Raphson from n/2 with an integer division a step;
+    its fixed point is the floor square root, so any exact floor square
+    root gives the same bits (n <= 1 returns n, as Alg. 4 does).  Here a
+    float32 sqrt seeds it, off by at most one over int32 (f32 rounds n to
+    24 bits and the sqrt to a few ulps, both far below 1 at y <= 46340),
+    and one integer step down or up makes it exact: no loop, no division,
+    which the TPU's VPU lacks.  The `y < _ISQRT_MAX` guard keeps
+    (y + 1)^2 from wrapping at the top of int32.  The MCU kernels
+    (`edge/emit_c.py`) keep Alg. 4's Newton loop, where a division is
+    cheap."""
     n = n.astype(jnp.int32)
-    x0 = jnp.maximum(n // 2, 1)
-
-    def body(_, x):
-        nxt = (x + n // jnp.maximum(x, 1)) // 2
-        return jnp.where(nxt < x, nxt, x)
-
-    # n in {0,1}: x0 heuristics.  The compare is traced before the loop:
-    # the order of the ops is part of the kernels' compiled code.
-    return jnp.where(n <= 1, n, jax.lax.fori_loop(0, 32, body, x0))
+    small = n <= 1
+    y = jnp.minimum(jnp.sqrt(n.astype(jnp.float32)).astype(jnp.int32),
+                    _ISQRT_MAX)
+    y = jnp.where(y * y > n, y - 1, y)
+    up = y + 1
+    y = jnp.where((y < _ISQRT_MAX) & (up * up <= n), up, y)
+    return jnp.where(small, n, y)
 
 
 SQUASH_GUARD_BITS = 10
@@ -182,7 +189,7 @@ def squash_q7_i32(s, in_frac: int, out_frac: int = 7, axis: int = -1):
     """`squash_q7` with the saturated result left in int32."""
     s32 = s.astype(jnp.int32)
     Q = jnp.sum(s32 * s32, axis=axis, keepdims=True)
-    return _squash_scale(s32, isqrt_newton(Q), Q, in_frac, out_frac)
+    return _squash_scale(s32, isqrt(Q), Q, in_frac, out_frac)
 
 
 def squash_q7(s, in_frac: int, out_frac: int = 7, axis: int = -1):
@@ -199,6 +206,8 @@ def squash_q7(s, in_frac: int, out_frac: int = 7, axis: int = -1):
         ratio = (S << (o - i + P)) // ((2^{2i} + Q) >> i)
         v     = sat8((ratio * s) >> P)
     Values: S <= 127*sqrt(D) < 2^9 for D <= 16, so int32 never overflows.
+    S is Alg. 4's floor square root, computed by `isqrt` without a loop or
+    a division; the ratio's one division per capsule stays.
     """
     return squash_q7_i32(s, in_frac, out_frac, axis).astype(jnp.int8)
 
@@ -264,8 +273,8 @@ def softmax_q7_approx(x, in_frac: int):
 
 def squash_q7_approx(s, in_frac: int, out_frac: int = 7):
     """ISLPED'22 approximate squash: Eq. 8 with the L2 norm replaced by
-    the L-inf norm M = max|s_i| — the 32-iteration Newton-Raphson
-    integer sqrt (Alg. 4, the routing loop's hot spot) disappears:
+    the L-inf norm M = max|s_i| — the integer square root of Alg. 4
+    (a 32-iteration Newton-Raphson loop on an MCU) disappears:
 
         ratio = (M << (o - i + P)) // ((1 << i) + (M*M >> i))
         v     = sat8((ratio * s) >> P)

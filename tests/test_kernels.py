@@ -11,6 +11,7 @@ from repro.kernels import ops, ref
 from repro.nn.backend import JnpBackend
 from repro.nn.config import CIFAR10, MNIST, SMALLNORB
 from repro.nn.plans import RoutingPlan
+from repro.nn.variants import _np_isqrt_newton, _np_squash_q7
 from repro.quant import int8_ops as q
 from repro.serving import EDGE_TINY
 
@@ -145,9 +146,50 @@ def test_squash_float_close():
                                atol=1e-5)
 
 
-def test_isqrt_exact_floor_sqrt():
-    n = jnp.asarray([0, 1, 2, 3, 4, 8, 15, 16, 17, 1023, 1024, 1 << 20,
-                     (1 << 30) + 12345], jnp.int32)
-    got = q.isqrt_newton(n)
-    want = jnp.asarray([int(np.sqrt(float(v))) for v in n], jnp.int32)
-    np.testing.assert_array_equal(got, want)
+def _isqrt_inputs(case):
+    if case == "below_2^22":
+        return np.arange(1 << 22, dtype=np.int32)
+    if case == "squares_pm1":
+        k = np.arange(1, 46341, dtype=np.int64)
+        n = np.concatenate([k * k - 1, k * k, k * k + 1])
+        return n[n < 2 ** 31].astype(np.int32)
+    return np.asarray([2 ** 31 - 1], np.int32)
+
+
+@pytest.mark.parametrize("case", ["below_2^22", "squares_pm1", "int32_max"])
+def test_isqrt_exact_floor_sqrt(case):
+    """int8_ops.isqrt gives Alg. 4's Newton-Raphson result bit for bit."""
+    n = _isqrt_inputs(case)
+    got = np.asarray(jax.jit(q.isqrt)(jnp.asarray(n)))
+    np.testing.assert_array_equal(got, _np_isqrt_newton(n))
+
+
+def _primitive_names(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        for v in eqn.params.values():
+            sub = getattr(v, "jaxpr", v)
+            if hasattr(sub, "eqns"):
+                yield from _primitive_names(sub)
+
+
+def test_isqrt_is_loop_and_division_free():
+    """The TPU's VPU has no divide: the squash's isqrt runs no loop and
+    no integer division (Alg. 4's Newton loop did 32 of each)."""
+    jaxpr = jax.make_jaxpr(q.isqrt)(jnp.zeros((8, 128), jnp.int32)).jaxpr
+    names = set(_primitive_names(jaxpr))
+    assert not names & {"while", "scan", "div", "rem"}, names
+
+
+def test_squash_q7_exact_at_domain_top():
+    """The Pallas squash over 32-D int8 capsules whose sums of squares
+    reach the kernels' top, Q = 32 * 128^2, against the NumPy mirror of
+    Eq. 8 with Alg. 4's Newton loop."""
+    D = 32
+    top = np.full((256, D), -128, np.int8)
+    top[:, 0] = np.arange(-128, 128)            # Q = 31 * 128^2 + v^2
+    s = np.concatenate([top, np.asarray(i8((512, D))),
+                        np.full((1, D), 127, np.int8)])
+    for in_frac in (0, 5):
+        got = ops.squash_q7(jnp.asarray(s), in_frac=in_frac, interpret=True)
+        np.testing.assert_array_equal(got, _np_squash_q7(s, in_frac))

@@ -44,7 +44,7 @@ def test_quantize_roundtrip_bound(vals):
 @settings(max_examples=100, deadline=None)
 @given(st.integers(min_value=0, max_value=2**31 - 1))
 def test_isqrt_is_floor_sqrt(n):
-    got = int(q.isqrt_newton(jnp.asarray([n], jnp.int32))[0])
+    got = int(q.isqrt(jnp.asarray([n], jnp.int32))[0])
     want = int(np.floor(np.sqrt(np.float64(n))))
     # guard fp edge at perfect squares
     while (want + 1) * (want + 1) <= n:
